@@ -112,16 +112,11 @@ class ArchProfile:
 def detect_arch(mach: Machine = V5E) -> ArchProfile:
     """Profile of the live JAX device (backend tag from the device platform,
     machine coordinates from ``mach`` — the nominal/overridden machine the
-    caller scores under). Falls back to ``"cpu"`` when no device backend is
-    importable, so classification never blocks startup."""
-    backend = "cpu"
-    try:  # pragma: no cover - depends on the container's device runtime
-        import jax
+    caller scores under). A backend that fails to start raises: it is
+    never classified as ``"cpu"``."""
+    import jax
 
-        backend = jax.devices()[0].platform
-    except Exception:  # noqa: BLE001 - any backend failure means "cpu"
-        pass
-    return ArchProfile.from_machine(mach, backend=backend)
+    return ArchProfile.from_machine(mach, backend=jax.devices()[0].platform)
 
 
 def arch_entry(profile: ArchProfile) -> str:

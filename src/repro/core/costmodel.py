@@ -97,7 +97,33 @@ class Machine:
         return self.hbm_bw / self.lanes
 
 
+#: The modeled v5e: scoring's machine wherever no TPU is attached.
 V5E = Machine()
+
+#: Published peaks of one chip, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud TPU v5e documentation (197 TFLOP/s bf16, 819 GB/s
+#: HBM, 1,600 Gbit/s of interconnect over 4 links).
+DEVICE_PEAKS = {
+    "TPU v5 lite": dict(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def device_machine(device=None) -> Machine:
+    """The machine selection scores against: on a TPU, the published peaks
+    of its ``device_kind`` (an unknown kind raises rather than assume
+    peaks); elsewhere the modeled :data:`V5E`."""
+    import jax
+
+    device = device or jax.devices()[0]
+    if device.platform != "tpu":
+        return V5E
+    try:
+        return Machine(**DEVICE_PEAKS[device.device_kind])
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for TPU device kind {device.device_kind!r}; "
+            f"known kinds: {sorted(DEVICE_PEAKS)}"
+        ) from None
 
 
 def default_grid_sizes(mach: Machine = V5E) -> Tuple[int, ...]:

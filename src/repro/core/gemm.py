@@ -18,9 +18,18 @@ strategy without touching this module. Built-ins:
 
   * ``xla``               — jnp einsum (CPU / dry-run lowering; selection
                             still exercised + logged, epilogue fused by XLA),
-  * ``pallas``            — the Stream-K++ Pallas kernels (TPU; epilogue
+  * ``pallas``            — the Stream-K++ Pallas kernels (TPU only; epilogue
                             fused into the kernel flush / fix-up phase),
   * ``pallas_interpret``  — same kernels, interpret mode (CPU-validated).
+
+The default backend is derived from the platform (:func:`platform_backend`):
+``pallas`` on TPU, ``xla`` elsewhere. Interpret mode is only ever chosen
+explicitly, and ``pallas`` on a non-TPU platform raises.
+
+Under an installed :class:`~repro.dist.sharding.ShardingPlan` with any
+divisor > 1, the backend runs inside ``jax.shard_map`` over the plan's mesh
+(:func:`_run_sharded`), so the kernel sees exactly the per-shard problem
+that :attr:`GemmOp.local` fingerprints.
 
 Entry points: :func:`gemm` (2-D weight, the original per-call surface),
 :func:`gemm_grouped` (stacked ``(G, K, N)`` expert weights — each group is
@@ -36,6 +45,9 @@ tests/benchmarks to introspect.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+import math
 import threading
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -43,6 +55,7 @@ from typing import Callable, Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.op import Epilogue, GemmOp, as_epilogue
 from repro.core.policies import Policy, TileConfig
@@ -54,6 +67,7 @@ from repro.core.quant import (
 )
 from repro.core.selector import KernelSelector, Selection, default_selector
 from repro.core.tuner import LEGACY_GRID
+from repro.dist.sharding import current_plan
 
 _state = threading.local()
 
@@ -216,6 +230,29 @@ register_backend("pallas", _make_pallas_backend(interpret=False))
 register_backend("pallas_interpret", _make_pallas_backend(interpret=True))
 
 
+@functools.lru_cache(maxsize=None)
+def _platform() -> str:
+    return jax.default_backend()
+
+
+def platform_backend() -> str:
+    """The default backend, derived once from the platform: the Stream-K++
+    Pallas kernels on TPU, XLA's dot elsewhere (CPU tests, dry-run
+    lowering). Interpret mode is never a default."""
+    return "pallas" if _platform() == "tpu" else "xla"
+
+
+def _resolve_backend(name: str) -> str:
+    get_backend(name)  # fail fast on unknown names
+    if name == "pallas" and _platform() != "tpu":
+        raise ValueError(
+            f"backend 'pallas' compiles Mosaic kernels for a TPU, but this "
+            f"process runs on {_platform()!r}; use 'pallas_interpret' to run "
+            "the kernels in interpret mode"
+        )
+    return name
+
+
 # ---------------------------------------------------------------------------
 # Dispatch context + selection log
 # ---------------------------------------------------------------------------
@@ -251,7 +288,7 @@ class GemmContext:
     """Ambient dispatch state: the selector, backend name, and log."""
 
     selector: KernelSelector
-    backend: str = "xla"  # any name in list_backends()
+    backend: str = field(default_factory=platform_backend)
     log: List[SelectionLogEntry] = field(default_factory=list)
 
 
@@ -265,16 +302,21 @@ def _ctx() -> GemmContext:
 
 @contextmanager
 def gemm_context(
-    selector: Optional[KernelSelector] = None, backend: Optional[str] = None
+    selector: Optional[KernelSelector] = None,
+    backend: Optional[str] = None,
+    log: Optional[List[SelectionLogEntry]] = None,
 ):
-    """Install a dispatch context for the duration of a trace/eval."""
+    """Install a dispatch context for the duration of a trace/eval.
+
+    Unset fields inherit from the enclosing context; ``log`` defaults to a
+    fresh list (pass :func:`current_log` to keep appending to the ambient
+    one)."""
     old = getattr(_state, "ctx", None)
     base = old or _ctx()
-    if backend is not None:
-        get_backend(backend)  # fail fast on unknown names
     _state.ctx = GemmContext(
         selector=selector if selector is not None else base.selector,
-        backend=backend if backend is not None else base.backend,
+        backend=_resolve_backend(backend) if backend is not None else base.backend,
+        log=log if log is not None else [],
     )
     try:
         yield _state.ctx
@@ -324,22 +366,85 @@ def _dispatch(
         # what actually runs (source "forced") — never the selector's own
         # pick, which may pair a different policy with this cfg/g
         sel = ctx.selector.select_partial(op, policy, cfg, g=g)
-    policy, cfg, grid = sel.policy, sel.cfg, sel.g
     ctx.log.append(SelectionLogEntry(op, sel, tag))
-    backend = get_backend(ctx.backend)
-    kwargs = dict(op=op, policy=policy, cfg=cfg, g=grid, bias=bias, operand=operand)
-    if scale is not None:
-        # only quantized ops pass the dequant operands: backends registered
-        # against the pre-quantization BackendFn signature keep serving
-        # dense traffic unchanged, and a quantized dispatch through one
-        # fails loudly (unexpected 'scale') instead of silently skipping
-        # the dequant stage
-        kwargs["scale"] = scale
-    if scale_a is not None:
-        kwargs["scale_a"] = scale_a
+    static = dict(policy=sel.policy, cfg=sel.cfg, g=sel.g)
     if b_bits != 8:
-        kwargs["b_bits"] = b_bits
-    return backend(x, w, **kwargs)
+        static["b_bits"] = b_bits
+    # only quantized ops pass the dequant operands: backends registered
+    # against the pre-quantization BackendFn signature keep serving dense
+    # traffic unchanged, and a quantized dispatch through one fails loudly
+    # (unexpected 'scale') instead of silently skipping the dequant stage
+    arrays = {"scale": scale, "scale_a": scale_a}
+    arrays = {k: v for k, v in arrays.items() if v is not None}
+    backend = get_backend(ctx.backend)
+    plan = current_plan()
+    if plan is None or max(*op.divisors, op.g_divisor) == 1:
+        return backend(x, w, op=op, bias=bias, operand=operand, **static, **arrays)
+    return _run_sharded(plan, backend, x, w, op, static, bias, operand, arrays)
+
+
+def _mesh_split(div: int, axes: Tuple[str, ...], mesh, dim: str):
+    """The mesh axes a GEMM dim with sharding divisor ``div`` splits over
+    (None when unsplit). A divisor the plan's axes cannot honour is a
+    caller bug: ``serve_gemm_div``/``train_gemm_div`` demote those."""
+    if div == 1:
+        return None
+    size = math.prod(mesh.shape[a] for a in axes)
+    if div != size:
+        raise ValueError(
+            f"gemm {dim} divisor {div} matches no split of the plan's mesh "
+            f"{dict(mesh.shape)} (axes {axes} have size {size})"
+        )
+    return axes[0] if len(axes) == 1 else axes
+
+
+def _run_sharded(plan, backend, x, w, op: GemmOp, static, bias, operand, arrays):
+    """Run ``backend`` on each shard of the plan's mesh (``jax.shard_map``).
+
+    A Mosaic kernel cannot be partitioned automatically, and the op
+    fingerprints the per-shard problem, so the kernel is given exactly that
+    problem: tokens (M) split over the batch axes, N over ``model`` for
+    column-parallel weights, K over ``model`` for row-parallel ones (whose
+    f32 partial products are then summed with ``psum``), and G over
+    ``model`` for expert-parallel groups."""
+    mesh = plan.mesh
+    axes = plan.gemm_axes()
+    dm, dn, dk = op.divisors
+    m_ax = _mesh_split(dm, axes["batch"], mesh, "M")
+    n_ax = _mesh_split(dn, axes["model"], mesh, "N")
+    k_ax = _mesh_split(dk, axes["model"], mesh, "K")
+    g_ax = _mesh_split(op.g_divisor, axes["model"], mesh, "G")
+    if k_ax is not None and not op.epilogue.is_none:
+        raise ValueError(
+            f"epilogue {op.epilogue.name!r} cannot run on the partial sums of a "
+            "K-sharded (row-parallel) gemm"
+        )
+    specs = dict(
+        bias=P(g_ax, n_ax),
+        operand=P(g_ax, m_ax, n_ax),
+        scale=P(g_ax, n_ax),
+        scale_a=P(g_ax, m_ax),
+    )
+    arrays = dict(arrays, bias=bias, operand=operand)
+    arrays = {k: v for k, v in arrays.items() if v is not None}
+    names = tuple(arrays)
+    # row-parallel partials are summed in f32, then cast once
+    run_op = dataclasses.replace(op, out_dtype="float32") if k_ax else op
+
+    def body(xs, ws, *extra):
+        kw = {"bias": None, "operand": None, **dict(zip(names, extra))}
+        out = backend(xs, ws, op=run_op, **static, **kw)
+        if k_ax is not None:
+            out = jax.lax.psum(out, k_ax).astype(op.out_dtype)
+        return out
+
+    return jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=(P(g_ax, m_ax, k_ax), P(g_ax, k_ax, n_ax), *(specs[n] for n in names)),
+        out_specs=P(g_ax, m_ax, None if k_ax else n_ax),
+        check_vma=False,
+    )(x, w, *arrays.values())
 
 
 def _check_epilogue(epilogue: Epilogue, bias, operand) -> None:

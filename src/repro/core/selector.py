@@ -194,7 +194,7 @@ class KernelSelector:
         self,
         sieve=_UNSET,
         db=_UNSET,
-        mach: costmodel.Machine = costmodel.V5E,
+        mach: Optional[costmodel.Machine] = None,
         policies: Sequence[Policy] = ALL_POLICIES,
         tile_configs: Sequence[TileConfig] = DEFAULT_TILE_CONFIGS,
         on_miss: Optional[MissHook] = None,
@@ -221,6 +221,7 @@ class KernelSelector:
                 calibration=legacy.get("calibration"),
             )
         self._state = state
+        mach = mach or costmodel.device_machine()
         self.mach = mach
         self.policies = tuple(policies)
         self.tile_configs = tuple(tile_configs)

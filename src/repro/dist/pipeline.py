@@ -76,7 +76,6 @@ def pipeline_apply(
     if mesh is None or axis is None or axis not in mesh.shape or s % mesh.shape[axis]:
         return _pipeline_local(stage_fn, stage_params, x)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]  # pipeline ranks; each owns s // n stages
@@ -110,7 +109,7 @@ def pipeline_apply(
         outs = jnp.where(j == n - 1, outs, jnp.zeros_like(outs))
         return lax.psum(outs, axis)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -118,5 +117,5 @@ def pipeline_apply(
             P(*((None,) * x.ndim)),
         ),
         out_specs=P(*((None,) * x.ndim)),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)

@@ -22,6 +22,7 @@ heads over 16 devices).
 
 from __future__ import annotations
 
+import functools
 import math
 import threading
 from contextlib import contextmanager
@@ -129,6 +130,12 @@ class ShardingPlan:
             "batch": self.axis_divisor("batch"),
             "model": int(self.mesh.shape.get("model", 1)),
         }
+
+    def gemm_axes(self) -> Dict[str, Tuple[str, ...]]:
+        """The mesh axes behind each :meth:`gemm_div` entry (empty where the
+        divisor is 1): the axes a sharded GEMM dispatch splits over."""
+        model = ("model",) if self.mesh.shape.get("model", 1) > 1 else ()
+        return {"batch": self._mesh_axes_for("batch"), "model": model}
 
     def demoted_dims(self, specs, mesh_axis: str = "model"):
         """Per-array divisibility probe: every (shape, axes, dim_index, dim)
@@ -259,9 +266,21 @@ def _init_leaf(spec: ArraySpec, key) -> jax.Array:
     return (jax.random.normal(key, spec.shape, jnp.float32) * std).astype(dtype)
 
 
-def materialize_tree(tree, key):
-    """Instantiate an ArraySpec tree with deterministic per-leaf RNG."""
+def materialize_tree(tree, key, shardings=None):
+    """Instantiate an ArraySpec tree with deterministic per-leaf RNG.
+
+    With ``shardings`` (a matching tree of ``Sharding``s) each leaf is made
+    by one jitted program straight into its sharding, so no device ever
+    holds a whole sharded leaf or its f32 draw (the jitted draw rounds
+    differently from the eager one, so the values differ)."""
     leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_spec)
     keys = jax.random.split(key, max(1, len(leaves)))
-    out = [_init_leaf(s, k) for s, k in zip(leaves, keys)]
+    if shardings is None:
+        out = [_init_leaf(s, k) for s, k in zip(leaves, keys)]
+    else:
+        shs = treedef.flatten_up_to(shardings)
+        out = [
+            jax.jit(functools.partial(_init_leaf, s), out_shardings=sh)(k)
+            for s, k, sh in zip(leaves, keys, shs)
+        ]
     return jax.tree.unflatten(treedef, out)

@@ -1,7 +1,7 @@
-"""Shared helpers for the Pallas GEMM kernels: compiler-params compat,
-padding, the fused epilogue applier, mixed-dtype MACs, and trace-time
-``pallas_call`` launch counting (how tests assert the fused grouped path
-really issues ONE kernel for all G expert groups)."""
+"""Shared helpers for the Pallas GEMM kernels: padding, the fused epilogue
+applier, mixed-dtype MACs, and trace-time ``pallas_call`` launch counting
+(how tests assert the fused grouped path really issues ONE kernel for all G
+expert groups)."""
 
 from __future__ import annotations
 
@@ -9,7 +9,6 @@ from contextlib import contextmanager
 from typing import Iterator, List, Optional
 
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.workpart import cdiv
 
@@ -45,12 +44,6 @@ def count_launches() -> Iterator[List[str]]:
         yield log
     finally:
         _launch_log = prev
-
-#: jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5; resolve
-#: whichever this install ships so the kernels run on both.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
 
 
 def pad_to(x, mults):

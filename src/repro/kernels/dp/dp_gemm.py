@@ -30,7 +30,6 @@ from repro.core.policies import TileConfig
 from repro.core.workpart import cdiv
 from repro.core.quant import unpack_int4
 from repro.kernels.common import (
-    CompilerParams,
     apply_epilogue,
     mixed_dot,
     record_launch,
@@ -163,7 +162,7 @@ def dp_gemm_region(
     # tile dim no longer writes disjoint blocks — it must be ARBITRARY
     # (sequential, last identical write wins), not PARALLEL.
     tile_sem = pltpu.ARBITRARY if n_prog != n_region else pltpu.PARALLEL
-    params = CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=(tile_sem, pltpu.ARBITRARY)
     )
     out_shape = jax.ShapeDtypeStruct((mp, np_), out_dtype)

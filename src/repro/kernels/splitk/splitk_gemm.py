@@ -22,7 +22,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.policies import TileConfig
 from repro.core.quant import unpack_int4
 from repro.core.workpart import cdiv
-from repro.kernels.common import CompilerParams, mixed_dot, record_launch
+from repro.kernels.common import mixed_dot, record_launch
 
 
 def _splitk_kernel(a_ref, b_ref, p_ref, acc_ref, *, kps: int, b_bits: int = 8):
@@ -95,7 +95,7 @@ def splitk_partials(
         out_shape=jax.ShapeDtypeStruct((s, mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((cfg.bm, cfg.bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # surplus programs of a padded grid alias the final tile's
             # partials slot: that dim must drop to ARBITRARY (see dp_gemm)
             dimension_semantics=(

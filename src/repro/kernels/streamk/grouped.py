@@ -61,7 +61,6 @@ from repro.core.policies import ALL_SK, Policy, PolicyKind, TileConfig
 from repro.core.quant import unpack_int4
 from repro.core.workpart import cdiv
 from repro.kernels.common import (
-    CompilerParams,
     apply_epilogue,
     mixed_dot,
     pad_to,
@@ -245,7 +244,7 @@ def _fused_call(
 
         def vec_index(x, j, tab):
             t, _ = _tile(x, j)
-            return (tab[t // nt], t % nt)
+            return (tab[t // nt], 0, t % nt)
 
         def row_index(x, j, tab):
             t, _ = _tile(x, j)
@@ -289,7 +288,7 @@ def _fused_call(
 
         def vec_index(i, k, tab):
             t = _tile_dp(i)
-            return (tab[t // nt], t % nt)
+            return (tab[t // nt], 0, t % nt)
 
         def row_index(i, k, tab):
             t = _tile_dp(i)
@@ -314,15 +313,18 @@ def _fused_call(
         pl.BlockSpec((cfg.bm, cfg.bk), a_index),
         pl.BlockSpec((1, bk_b, cfg.bn), b_index),
     ]
+    # per-group row vectors ride as (G, 1, Np): a (1, bn) block of a (G, Np)
+    # array would leave a second-minor block dim the TPU cannot tile
+    vec_spec = pl.BlockSpec((None, 1, cfg.bn), vec_index)
     if scale is not None:
-        operands.append(scale)
-        in_specs.append(pl.BlockSpec((1, cfg.bn), vec_index))
+        operands.append(scale[:, None, :])
+        in_specs.append(vec_spec)
     if scale_a is not None:
         operands.append(scale_a)
         in_specs.append(pl.BlockSpec((cfg.bm, 1), row_index))
     if bias is not None:
-        operands.append(bias)
-        in_specs.append(pl.BlockSpec((1, cfg.bn), vec_index))
+        operands.append(bias[:, None, :])
+        in_specs.append(vec_spec)
     if operand is not None:
         operands.append(operand)
         in_specs.append(pl.BlockSpec((cfg.bm, cfg.bn), c_index))
@@ -339,7 +341,7 @@ def _fused_call(
         ),
         out_shape=jax.ShapeDtypeStruct((rp, np_), out_dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         name=name,
     )(tab, *operands)
 

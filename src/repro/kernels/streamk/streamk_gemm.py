@@ -48,7 +48,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.quant import unpack_int4
 from repro.core.workpart import Partition, cdiv
 from repro.kernels.common import (
-    CompilerParams,
     apply_epilogue,
     mixed_dot,
     record_launch,
@@ -164,7 +163,7 @@ def streamk_phase1(a, b, part: Partition, *, interpret: bool = False, b_bits: in
         ),
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY),
         ),
         name=f"streamk_p1_{cfg.name}_g{part.g}",
@@ -271,7 +270,7 @@ def streamk_fixup(
             (part.sk_tiles, cfg.bm, cfg.bn), out_dtype
         ),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,),
         ),
         name=f"streamk_fixup_{cfg.name}",

@@ -13,6 +13,7 @@ never touches jax device state — required because the dry-run must set
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None):
@@ -30,11 +31,13 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
         n *= d
     assert n in (256, 512), f"production pod sizes are 256/512 chips, got {n}"
     axes = ("pod", "data", "model") if len(shape) == 3 else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Whatever this host offers (tests / examples): (data, model)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh(
+        (n // model, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

@@ -91,6 +91,7 @@ from repro.core.gossip import GossipExchange
 from repro.core.selector import KernelSelector, SelectorState
 from repro.core.tuner import TuningDatabase
 from repro.dist.sharding import ShardingPlan, materialize_tree, use_plan
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.train import preset_config
 from repro.models import build_model
@@ -212,7 +213,8 @@ def run_with_gossip(engine, gossip, every, max_steps: int = 10_000):
     return finished
 
 
-def main() -> int:
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI's flags (see the module doc)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
     ap.add_argument("--preset", default="100m", choices=["full", "reduced", "100m"])
@@ -222,7 +224,9 @@ def main() -> int:
     ap.add_argument("--max-new-tokens", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--dtype", default="float32")
+    ap.add_argument(
+        "--dtype", default=None, help="override the config's dtype (default: keep it)"
+    )
     ap.add_argument(
         "--paged",
         action="store_true",
@@ -380,7 +384,12 @@ def main() -> int:
         "live backend, so records only federate as direct hits within the "
         "same device class ('off': the legacy single-class 'default')",
     )
-    args = ap.parse_args()
+    return ap
+
+
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and cross-check the serving flags."""
+    args = build_parser().parse_args(argv)
     if args.workers < 1:
         raise SystemExit(f"--workers must be >= 1, got {args.workers}")
     if args.merge_journals and not args.journal:
@@ -389,189 +398,262 @@ def main() -> int:
         raise SystemExit(f"--gossip-every must be >= 0, got {args.gossip_every}")
     if args.gossip_every and not args.journal:
         raise SystemExit("--gossip-every requires --journal")
+    return args
 
+
+def load_config(args):
+    """The model config ``args`` select (preset, optional dtype override)."""
     cfg = preset_config(args.arch, args.preset)
     if args.dtype:
         cfg = dataclasses.replace(cfg, dtype=args.dtype)
     if cfg.family == "encdec":
         raise SystemExit("serve CLI drives decoder-only archs; see examples/ for enc-dec")
-    model = build_model(cfg)
-    params = materialize_tree(model.param_specs(), jax.random.PRNGKey(args.seed))
-    if args.quantize != "none":
-        # every decoder-only arch serves through LM, which owns the
-        # quantization entry point (enc-dec was rejected above)
-        bits = 4 if args.quantize == "int4" else 8
-        act_bits = 8 if args.quantize == "int8-dynamic" else None
-        params, n_quant, n_skipped = model.quantize_weights(
-            params, bits=bits, act_bits=act_bits
-        )
-        log.info(
-            "quantized %d weight leaves to int%d (per-output-channel "
-            "scales%s); %d float leaves skipped",
-            n_quant,
-            bits,
-            ", dynamic int8 activations" if act_bits else "",
-            n_skipped,
-        )
+    return cfg
 
-    grid_sizes = None
-    if args.grid_sweep:
-        try:
-            grid_sizes = tuple(
-                sorted({int(x) for x in args.grid_sweep.split(",") if x.strip()})
+
+def make_plan(args):
+    """A (data, model=N) host-mesh sharding plan for ``--mesh-model N``."""
+    if not args.mesh_model:
+        return None
+    mesh = make_host_mesh(model=args.mesh_model)
+    plan = ShardingPlan(mesh)
+    log.info(
+        "mesh plan installed: %s -> gemm divisors %s",
+        dict(mesh.shape),
+        plan.gemm_div(),
+    )
+    return plan
+
+
+def init_params(model, args, plan=None):
+    """Random weights from ``--seed`` (sharded over ``plan``'s mesh when one
+    is given), quantized at load per ``--quantize``."""
+    specs = model.param_specs()
+    shardings = plan.tree_shardings(specs) if plan is not None else None
+    params = materialize_tree(specs, jax.random.PRNGKey(args.seed), shardings)
+    if args.quantize == "none":
+        return params
+    # every decoder-only arch serves through LM, which owns the
+    # quantization entry point (enc-dec was rejected by load_config)
+    bits = 4 if args.quantize == "int4" else 8
+    act_bits = 8 if args.quantize == "int8-dynamic" else None
+    params, n_quant, n_skipped = model.quantize_weights(
+        params, bits=bits, act_bits=act_bits
+    )
+    log.info(
+        "quantized %d weight leaves to int%d (per-output-channel "
+        "scales%s); %d float leaves skipped",
+        n_quant,
+        bits,
+        ", dynamic int8 activations" if act_bits else "",
+        n_skipped,
+    )
+    return params
+
+
+def parse_grid_sizes(args):
+    """``--grid-sweep`` as a sorted tuple (None: the machine's default)."""
+    if not args.grid_sweep:
+        return None
+    try:
+        grid_sizes = tuple(
+            sorted({int(x) for x in args.grid_sweep.split(",") if x.strip()})
+        )
+    except ValueError:
+        raise SystemExit(f"bad --grid-sweep {args.grid_sweep!r}") from None
+    if not grid_sizes or min(grid_sizes) < 1:
+        raise SystemExit(f"bad --grid-sweep {args.grid_sweep!r}")
+    return grid_sizes
+
+
+def load_machine(args):
+    """The machine selection scores against: the device's published peaks
+    (:func:`~repro.core.costmodel.device_machine`), or ``--mach-json``
+    overrides on top of the modeled v5e."""
+    if not args.mach_json:
+        return costmodel.device_machine()
+    try:
+        with open(args.mach_json) as f:
+            mach = machine_from_json(json.load(f))
+    except (OSError, ValueError, TypeError) as e:
+        raise SystemExit(f"bad --mach-json {args.mach_json!r}: {e}") from None
+    log.info(
+        "machine overrides: peak=%.1f TF/s bw=%.0f GB/s lanes=%d",
+        mach.peak_flops / 1e12,
+        mach.hbm_bw / 1e9,
+        mach.lanes,
+    )
+    return mach
+
+
+def warm_db(args, w: int, arch_cls: str) -> TuningDatabase:
+    """Worker ``w``'s warm-start database — each simulated process
+    loads its own copy, exactly as K real processes would: the snapshot,
+    then (without --merge-journals) the base journal plus the worker's
+    OWN shard from the previous fleet run, or (with --merge-journals)
+    the federation of every shard the whole fleet ever wrote."""
+    if args.db and os.path.exists(args.db):
+        db = TuningDatabase.load(args.db, arch=arch_cls)
+    else:
+        db = TuningDatabase(arch=arch_cls)
+    if not args.journal:
+        return db
+    if args.merge_journals:
+        shards = existing_journal_shards(args.journal)
+        if shards:
+            # last-writer-wins among the peer shards, then applied
+            # ON TOP of the snapshot (journals post-date it; their
+            # producer clocks are not comparable to the snapshot's)
+            merged, rep = merge_journal_shards(
+                shards,
+                into=TuningDatabase(arch=arch_cls),
+                missing_ok=True,
             )
-        except ValueError:
-            raise SystemExit(f"bad --grid-sweep {args.grid_sweep!r}") from None
-        if not grid_sizes or min(grid_sizes) < 1:
-            raise SystemExit(f"bad --grid-sweep {args.grid_sweep!r}")
+            apply_journal_db(db, merged)
+            log.info(
+                "federated warm start: %d shards -> %d records "
+                "(%d conflicts, %d superseded, %d load errors)",
+                rep.sources,
+                len(db.records),
+                rep.conflicts,
+                rep.superseded,
+                rep.load_errors,
+            )
+        return db
+    db.replay_journal(args.journal, missing_ok=True)
+    own = shard_journal_path(args.journal, w, args.workers)
+    if own != args.journal:
+        # a repeat fleet run must not silently cold-start: each
+        # worker at least replays what IT learned last time
+        db.replay_journal(own, missing_ok=True)
+        siblings = [
+            p
+            for p in existing_journal_shards(args.journal)
+            if p not in (args.journal, own)
+        ]
+        if siblings:
+            log.info(
+                "worker %d: %d sibling journal shards exist but "
+                "--merge-journals is off; pass it to warm-start "
+                "from the whole fleet",
+                w,
+                len(siblings),
+            )
+    return db
 
-    mach = costmodel.V5E
-    if args.mach_json:
-        try:
-            with open(args.mach_json) as f:
-                mach = machine_from_json(json.load(f))
-        except (OSError, ValueError, TypeError) as e:
-            raise SystemExit(f"bad --mach-json {args.mach_json!r}: {e}") from None
-        log.info(
-            "machine overrides: peak=%.1f TF/s bw=%.0f GB/s lanes=%d",
-            mach.peak_flops / 1e12,
-            mach.hbm_bw / 1e9,
-            mach.lanes,
+
+def build_worker(args, w: int, *, mach, grid_sizes, arch_cls, arch_profile=None):
+    """Worker ``w``'s (selector, adaptive tuner or None)."""
+    use_artifacts = bool(args.db or args.journal or args.adapt or args.calibrate)
+    if use_artifacts:
+        db = warm_db(args, w, arch_cls)
+        # a calibration replayed from the journal/snapshot warm-starts
+        # model-first dispatch even without --calibrate
+        calibration = db.calibration
+        if args.calibrate:
+            try:
+                db.set_calibration(calibrate_db(db, base=mach))
+            except CalibrationError as e:
+                log.warning("worker %d: calibration skipped: %s", w, e)
+            else:
+                calibration = db.calibration
+                if args.journal:
+                    append_calibration(
+                        shard_journal_path(args.journal, w, args.workers),
+                        calibration,
+                    )
+        sieve = db.build_sieve() if db.n_records() else None
+        selector = KernelSelector(
+            state=SelectorState(
+                db=db, sieve=sieve, calibration=calibration, arch=arch_cls
+            ),
+            mach=mach,
+            grid_sizes=grid_sizes,
         )
+        log.info(
+            "worker %d warm-start: %d tuned records + %d cross-arch "
+            "(%d dropped at load), calibration %s, arch %s",
+            w,
+            len(db.records),
+            db.n_records() - len(db.records),
+            db.load_errors,
+            "installed" if calibration is not None else "absent",
+            arch_cls,
+        )
+    else:
+        selector = KernelSelector(
+            mach=mach,
+            grid_sizes=grid_sizes,
+            state=SelectorState(arch=arch_cls),
+        )
+    if arch_profile is not None and args.journal:
+        # declare this producer's coordinates in its shard, so every
+        # consumer of the journal knows the machine behind the class
+        append_arch(
+            shard_journal_path(args.journal, w, args.workers), arch_profile
+        )
+    adaptive = None
+    if args.adapt:
+        adaptive = AdaptiveTuner(
+            selector,
+            config=AdaptiveConfig(
+                budget_s=args.adapt_budget,
+                hot_threshold=args.adapt_threshold,
+                top_k=args.top_k,
+            ),
+            journal=shard_journal_path(args.journal, w, args.workers)
+            if args.journal
+            else None,
+        )
+    return selector, adaptive
+
+
+def make_engine(args, model, params, adaptive=None):
+    """The serving engine ``args`` select: the paged engine with ``--paged``,
+    else the dense slot engine. It dispatches under the ambient gemm
+    context (and sharding plan) it is built and run in."""
+    adapt_every = args.adapt_every if args.adapt else 0
+    if args.paged:
+        max_pages = args.max_pages or (args.slots * args.max_seq // args.page_size)
+        return PagedServeEngine(
+            model,
+            params,
+            PagedServeConfig(
+                page_size=args.page_size,
+                max_pages=max_pages,
+                max_active=args.slots,
+                max_seq=args.max_seq,
+                prefill_chunk=args.prefill_chunk,
+                eos=-1,
+                seed=args.seed,
+            ),
+            adaptive=adaptive,
+            adapt_every=adapt_every,
+        )
+    return ServeEngine(
+        model,
+        params,
+        ServeConfig(n_slots=args.slots, max_seq=args.max_seq, eos=-1),
+        adaptive=adaptive,
+        adapt_every=adapt_every,
+    )
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg = load_config(args)
+    model = build_model(cfg)
+    plan = make_plan(args)
+    params = init_params(model, args, plan)
+    grid_sizes = parse_grid_sizes(args)
+    mach = load_machine(args)
     arch_profile = None
     arch_cls = DEFAULT_ARCH
     if args.arch_class == "auto":
         arch_profile = detect_arch(mach)
         arch_cls = arch_profile.cls
         log.info("arch class: %s", arch_cls)
-    use_artifacts = bool(args.db or args.journal or args.adapt or args.calibrate)
-
-    def warm_db(w: int) -> TuningDatabase:
-        """Worker ``w``'s warm-start database — each simulated process
-        loads its own copy, exactly as K real processes would: the snapshot,
-        then (without --merge-journals) the base journal plus the worker's
-        OWN shard from the previous fleet run, or (with --merge-journals)
-        the federation of every shard the whole fleet ever wrote."""
-        if args.db and os.path.exists(args.db):
-            db = TuningDatabase.load(args.db, arch=arch_cls)
-        else:
-            db = TuningDatabase(arch=arch_cls)
-        if args.journal:
-            if args.merge_journals:
-                shards = existing_journal_shards(args.journal)
-                if shards:
-                    # last-writer-wins among the peer shards, then applied
-                    # ON TOP of the snapshot (journals post-date it; their
-                    # producer clocks are not comparable to the snapshot's)
-                    merged, rep = merge_journal_shards(
-                        shards,
-                        into=TuningDatabase(arch=arch_cls),
-                        missing_ok=True,
-                    )
-                    apply_journal_db(db, merged)
-                    log.info(
-                        "federated warm start: %d shards -> %d records "
-                        "(%d conflicts, %d superseded, %d load errors)",
-                        rep.sources,
-                        len(db.records),
-                        rep.conflicts,
-                        rep.superseded,
-                        rep.load_errors,
-                    )
-            else:
-                db.replay_journal(args.journal, missing_ok=True)
-                own = shard_journal_path(args.journal, w, args.workers)
-                if own != args.journal:
-                    # a repeat fleet run must not silently cold-start: each
-                    # worker at least replays what IT learned last time
-                    db.replay_journal(own, missing_ok=True)
-                    siblings = [
-                        p
-                        for p in existing_journal_shards(args.journal)
-                        if p not in (args.journal, own)
-                    ]
-                    if siblings:
-                        log.info(
-                            "worker %d: %d sibling journal shards exist but "
-                            "--merge-journals is off; pass it to warm-start "
-                            "from the whole fleet",
-                            w,
-                            len(siblings),
-                        )
-        return db
-
-    def build_worker(w: int):
-        if use_artifacts:
-            db = warm_db(w)
-            # a calibration replayed from the journal/snapshot warm-starts
-            # model-first dispatch even without --calibrate
-            calibration = db.calibration
-            if args.calibrate:
-                try:
-                    db.set_calibration(calibrate_db(db, base=mach))
-                except CalibrationError as e:
-                    log.warning("worker %d: calibration skipped: %s", w, e)
-                else:
-                    calibration = db.calibration
-                    if args.journal:
-                        append_calibration(
-                            shard_journal_path(args.journal, w, args.workers),
-                            calibration,
-                        )
-            sieve = db.build_sieve() if db.n_records() else None
-            selector = KernelSelector(
-                state=SelectorState(
-                    db=db, sieve=sieve, calibration=calibration, arch=arch_cls
-                ),
-                mach=mach,
-                grid_sizes=grid_sizes,
-            )
-            log.info(
-                "worker %d warm-start: %d tuned records + %d cross-arch "
-                "(%d dropped at load), calibration %s, arch %s",
-                w,
-                len(db.records),
-                db.n_records() - len(db.records),
-                db.load_errors,
-                "installed" if calibration is not None else "absent",
-                arch_cls,
-            )
-        else:
-            selector = KernelSelector(
-                mach=mach,
-                grid_sizes=grid_sizes,
-                state=SelectorState(arch=arch_cls),
-            )
-        if arch_profile is not None and args.journal:
-            # declare this producer's coordinates in its shard, so every
-            # consumer of the journal knows the machine behind the class
-            append_arch(
-                shard_journal_path(args.journal, w, args.workers), arch_profile
-            )
-        adaptive = None
-        if args.adapt:
-            adaptive = AdaptiveTuner(
-                selector,
-                config=AdaptiveConfig(
-                    budget_s=args.adapt_budget,
-                    hot_threshold=args.adapt_threshold,
-                    top_k=args.top_k,
-                ),
-                journal=shard_journal_path(args.journal, w, args.workers)
-                if args.journal
-                else None,
-            )
-        return selector, adaptive
-
-    plan = None
-    if args.mesh_model:
-        mesh = make_host_mesh(model=args.mesh_model)
-        plan = ShardingPlan(mesh)
-        log.info(
-            "mesh plan installed: %s -> gemm divisors %s",
-            dict(mesh.shape),
-            plan.gemm_div(),
-        )
 
     # deterministic request stream, dealt round-robin across the workers
     rng = np.random.default_rng(args.seed)
@@ -589,7 +671,17 @@ def main() -> int:
     # build every worker's state BEFORE any engine serves: a real fleet's
     # processes all start from the pre-run artifacts, so worker 1 must not
     # warm-start from what worker 0 journaled moments ago in this same run
-    worker_state = [build_worker(w) for w in range(args.workers)]
+    worker_state = [
+        build_worker(
+            args,
+            w,
+            mach=mach,
+            grid_sizes=grid_sizes,
+            arch_cls=arch_cls,
+            arch_profile=arch_profile,
+        )
+        for w in range(args.workers)
+    ]
     t0 = time.time()
     with use_plan(plan):
         for w in range(args.workers):
@@ -605,35 +697,7 @@ def main() -> int:
                 ]
                 gossip = GossipExchange(selector, peers)
             with gemm_context(selector=selector) as ctx:
-                if args.paged:
-                    max_pages = args.max_pages or (
-                        args.slots * args.max_seq // args.page_size
-                    )
-                    engine = PagedServeEngine(
-                        model,
-                        params,
-                        PagedServeConfig(
-                            page_size=args.page_size,
-                            max_pages=max_pages,
-                            max_active=args.slots,
-                            max_seq=args.max_seq,
-                            prefill_chunk=args.prefill_chunk,
-                            eos=-1,
-                            seed=args.seed,
-                        ),
-                        adaptive=adaptive,
-                        adapt_every=args.adapt_every if args.adapt else 0,
-                    )
-                else:
-                    engine = ServeEngine(
-                        model,
-                        params,
-                        ServeConfig(
-                            n_slots=args.slots, max_seq=args.max_seq, eos=-1
-                        ),
-                        adaptive=adaptive,
-                        adapt_every=args.adapt_every if args.adapt else 0,
-                    )
+                engine = make_engine(args, model, params, adaptive)
                 wprompts = prompts[w :: args.workers]
                 if args.replay != "off":
                     done.extend(

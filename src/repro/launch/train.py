@@ -19,6 +19,7 @@ import jax
 from repro.configs import get_config, get_reduced, list_archs
 from repro.data import SyntheticLMData
 from repro.dist.sharding import materialize_tree
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import make_optimizer, warmup_cosine
 from repro.train import Trainer, TrainerConfig, init_train_state
@@ -69,8 +70,11 @@ def main() -> int:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--dtype", default="float32", help="override model dtype on CPU")
+    ap.add_argument(
+        "--dtype", default=None, help="override the config's dtype (default: keep it)"
+    )
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = preset_config(args.arch, args.preset)
     if args.dtype:

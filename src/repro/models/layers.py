@@ -728,7 +728,6 @@ def moe_apply_shard_map(
     Capacity semantics: per data-row capacity, token-major priority — the
     same contract as ``moe_impl="hinted"``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.dist.sharding import current_plan
@@ -816,7 +815,7 @@ def moe_apply_shard_map(
 
     batch_spec = P(dp_axes if len(dp_axes) > 1 else (dp_axes[0] if dp_axes else None))
     x_spec = P(batch_spec[0], None, None)
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -827,7 +826,7 @@ def moe_apply_shard_map(
             P("model", None, None),
         ),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(
         x,
         p["router"],

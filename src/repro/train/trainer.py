@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import CheckpointManager, install_sigterm_handler
+from repro.core.gemm import current_log, gemm_context
 from repro.data import SyntheticLMData
 from repro.dist.compression import ErrorFeedback
 from repro.utils.logging import get_logger
@@ -120,8 +121,9 @@ def make_train_step(
     """
 
     def loss_fn(params, batch):
-        loss, metrics = model.loss_fn(params, batch, div=div)
-        return loss, metrics
+        # XLA's dot: the Pallas GEMMs have no custom_vjp, so cannot be differentiated
+        with gemm_context(backend="xla", log=current_log()):
+            return model.loss_fn(params, batch, div=div)
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 

@@ -9,6 +9,7 @@ import dataclasses
 import json
 import logging
 
+import jax
 import pytest
 
 from repro.core.arch import DEFAULT_ARCH, ArchProfile, append_arch, detect_arch
@@ -303,3 +304,13 @@ def test_federate_explicit_matching_geometry_is_accepted(tmp_path):
     )
     state = federate_selector(sel, journals=[shard], capacity=512, fp_rate=0.05)
     assert state.merged == len(SIZES) + 1
+
+
+def test_detect_arch_raises_when_the_backend_fails(monkeypatch):
+    # a backend that fails to start is an error, never a "cpu" class
+    def broken():
+        raise RuntimeError("backend down")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="backend down"):
+        detect_arch()

@@ -85,14 +85,14 @@ from repro.checkpoint import CheckpointManager
 
 mode, ckdir = sys.argv[1], sys.argv[2]
 if mode == "save":
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     w = jax.device_put(np.arange(64, dtype=np.float32).reshape(8, 8),
                        NamedSharding(mesh, P("data", None)))
     CheckpointManager(ckdir).save(1, {{"w": w}})
     print("SAVED")
 else:
     # restore onto a DIFFERENT mesh: 2x4 with model sharding on dim 1
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     target = {{"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}}
     sh = {{"w": NamedSharding(mesh, P("data", "model"))}}
     restored, step = CheckpointManager(ckdir).restore(target, shardings=sh)

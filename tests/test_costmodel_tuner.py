@@ -94,3 +94,19 @@ def test_default_selector_scores_all():
     out = sel.select(256, 256, 256)
     assert out.source == "fallback"
     assert sel.stats.evals >= len(ALL_POLICIES)
+
+
+# -- the selector's machine per device kind ----------------------------------
+
+
+def test_device_machine_keys_tpu_peaks_by_device_kind():
+    from types import SimpleNamespace
+
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    mach = costmodel.device_machine(v5e)
+    assert (mach.peak_flops, mach.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="TPU v9"):
+        costmodel.device_machine(SimpleNamespace(platform="tpu", device_kind="TPU v9"))
+    # off the chip, scoring keeps the modeled v5e
+    assert costmodel.device_machine(SimpleNamespace(platform="cpu", device_kind="cpu")) is costmodel.V5E
+    assert KernelSelector().mach is costmodel.V5E

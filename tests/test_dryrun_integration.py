@@ -29,7 +29,7 @@ from repro.train import make_train_step
 
 arch, kind = sys.argv[1], sys.argv[2]
 cfg = get_reduced(arch)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = ShardingPlan(mesh, {"seq": "model"} if kind == "train" else {})
 model = build_model(cfg)
 specs = model.param_specs()

@@ -25,7 +25,7 @@ from repro.train import init_train_state, make_train_step
 
 mode, ckdir, mesh_spec = sys.argv[1], sys.argv[2], sys.argv[3]
 d_sz, m_sz = (int(x) for x in mesh_spec.split("x"))
-mesh = jax.make_mesh((d_sz, m_sz), ("data", "model"))
+mesh = jax.make_mesh((d_sz, m_sz), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = ShardingPlan(mesh)
 
 cfg = dataclasses.replace(get_reduced("granite-8b"), dtype="float32")

@@ -600,7 +600,7 @@ def test_mesh_local_fingerprints_federate_across_hosts():
     a record tuned on host A is an exact DB hit on host B."""
     from repro.dist.sharding import ShardingPlan, ambient_gemm_div, use_plan
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     plan = ShardingPlan(mesh)
     with use_plan(plan):
         div = ambient_gemm_div()
@@ -636,7 +636,7 @@ def test_serve_engine_derives_div_from_ambient_plan():
     cfg = tiny("granite-8b")
     model = build_model(cfg)
     params = materialize_tree(model.param_specs(), jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with use_plan(ShardingPlan(mesh)):
         eng = ServeEngine(model, params, ServeConfig(n_slots=2, max_seq=32, eos=-1))
         assert eng.div == {"batch": 2, "model": 4}

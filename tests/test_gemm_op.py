@@ -369,15 +369,3 @@ def test_non_f32_plain_ops_read_mnk_artifacts():
     assert bf16_op.key in db2.records
     sel3 = KernelSelector(db=db2)
     assert sel3.select_op(bf16_op).source == "tuned"
-
-
-def test_gemm_divisors_key_local_shape():
-    sel = default_selector()
-    x = jnp.ones((4, 8, 32), jnp.float32)
-    w = jnp.ones((32, 64), jnp.float32)
-    with gemm_context(selector=sel) as ctx:
-        gemm(x, w, divisors=(4, 2, 1))
-    e = ctx.log[0]
-    assert e.op.global_mnk == (32, 64, 32)
-    assert e.op.local == (8, 32, 32)
-    assert e.op.key == (8, 32, 32)  # plain op -> legacy key

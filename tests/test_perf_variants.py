@@ -80,7 +80,7 @@ p0 = jax.tree.map(lambda a: a[0], params["layers"])["moe"]
 r = np.random.default_rng(0)
 x = jnp.asarray(r.normal(size=(8, 16, cfg.d_model)) * 0.3, jnp.float32)
 ref, _ = moe_apply(p0, x, cfg, div={})
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg2 = dataclasses.replace(cfg, moe_impl="shard_map")
 with use_plan(ShardingPlan(mesh)):
     got, _ = jax.jit(lambda p, x: moe_apply(p, x, cfg2, div={"batch": 4, "model": 2}))(p0, x)

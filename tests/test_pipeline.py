@@ -39,7 +39,7 @@ def seq_apply(params, x):
 ref = jax.vmap(lambda xb: seq_apply(params, xb))(x.reshape(M * MB // MB, MB, D).reshape(M, MB, D))
 ref = jnp.stack([seq_apply(params, x[m]) for m in range(M)])
 
-mesh = jax.make_mesh((4, 2), ("pod", "data"))
+mesh = jax.make_mesh((4, 2), ("pod", "data"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 staged = split_stages(params, 4)
 got = jax.jit(lambda sp, x: pipeline_apply(stage_fn, sp, x, mesh=mesh, axis="pod"))(staged, x)
 err = float(jnp.max(jnp.abs(got - ref)))

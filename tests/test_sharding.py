@@ -23,7 +23,7 @@ from repro.dist.sharding import (
 def mesh():
     # 1-device mesh with named axes of size 1 — rule plumbing is mesh-size
     # independent; divisibility tests use the subprocess below.
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_spec_for_basic(mesh):
@@ -84,7 +84,7 @@ import jax
 from jax.sharding import PartitionSpec as P
 from repro.dist.sharding import ArraySpec, ShardingPlan
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = ShardingPlan(mesh)
 # divisible: shard
 s1 = plan.spec_for(ArraySpec((6, 8), "float32", ("embed", "heads")))
@@ -118,3 +118,24 @@ def test_divisibility_on_real_multidevice_mesh():
     )
     assert r.returncode == 0, r.stderr
     assert "OK" in r.stdout
+
+
+def test_materialize_tree_places_each_leaf_on_its_sharding(mesh):
+    from jax.sharding import NamedSharding
+
+    from repro.dist.sharding import materialize_tree
+
+    specs = {"w": ArraySpec((8, 16), "bfloat16", ("embed", "heads")),
+             "b": ArraySpec((16,), "float32", (None,), init="zeros")}
+    sh = NamedSharding(mesh, P())
+    tree = materialize_tree(specs, jax.random.PRNGKey(0), {"w": sh, "b": sh})
+    assert tree["w"].sharding == sh and tree["w"].dtype == jax.numpy.bfloat16
+    assert float(jax.numpy.abs(tree["b"]).max()) == 0.0
+    assert 0.1 < float(jax.numpy.std(tree["w"].astype("float32"))) < 0.5  # ~1/sqrt(8)
+
+
+def test_gemm_axes_name_the_mesh_axes_behind_gemm_div(mesh):
+    plan = ShardingPlan(mesh)
+    # a 1x1 mesh splits nothing: every divisor is 1, so no axes
+    assert plan.gemm_div() == {"batch": 1, "model": 1}
+    assert plan.gemm_axes() == {"batch": (), "model": ()}

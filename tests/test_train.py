@@ -223,3 +223,21 @@ def test_trainer_defaults_div_from_ambient_probe(monkeypatch):
     )
     t2 = Trainer(model, opt, data, TrainerConfig(total_steps=1), jit=False)
     assert t2.div == {"batch": 1, "model": 1}
+
+
+def test_train_step_differentiates_on_xla_under_any_ambient_backend():
+    """Pallas GEMMs have no custom_vjp: the train step pins XLA's dot, and
+    its selections still reach the ambient log."""
+    from repro.core.gemm import gemm_context
+
+    cfg = tiny("granite-8b")
+    model = build_model(cfg)
+    params = materialize_tree(model.param_specs(), jax.random.PRNGKey(0))
+    opt = make_optimizer("sgd", constant(1e-2))
+    data = SyntheticLMData(cfg, batch=2, seq_len=16, seed=0)
+    batch = {k: jnp.asarray(v) for k, v in data.batch_at(0).items()}
+    state = init_train_state(model, opt, params)
+    with gemm_context(backend="pallas_interpret") as ctx:
+        _, metrics = jax.jit(make_train_step(model, opt))(state, batch)
+    assert np.isfinite(float(metrics["loss"]))
+    assert ctx.log and ctx.backend == "pallas_interpret"
