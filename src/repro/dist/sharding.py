@@ -22,7 +22,6 @@ heads over 16 devices).
 
 from __future__ import annotations
 
-import functools
 import math
 import threading
 from contextlib import contextmanager
@@ -250,37 +249,38 @@ def abstract_tree(tree):
     return jax.tree.map(lambda s: s.abstract(), tree, is_leaf=_is_spec)
 
 
-def _init_leaf(spec: ArraySpec, key) -> jax.Array:
-    dtype = jnp.dtype(spec.dtype)
-    if spec.init == "zeros":
-        return jnp.zeros(spec.shape, dtype)
-    if spec.init == "ones":
-        return jnp.ones(spec.shape, dtype)
+def _init_leaf(shape: Tuple[int, ...], dtype: str, init: str, key) -> jax.Array:
+    dtype = jnp.dtype(dtype)
+    if init == "zeros":
+        return jnp.zeros(shape, dtype)
+    if init == "ones":
+        return jnp.ones(shape, dtype)
     # fan-in-scaled normal; the stacked-layer axis (leading) never counts as
     # fan-in because specs are stacked after the per-layer shape is fixed.
-    if len(spec.shape) >= 2:
-        fan_in = spec.shape[-2]
+    if len(shape) >= 2:
+        fan_in = shape[-2]
     else:
-        fan_in = spec.shape[-1]
+        fan_in = shape[-1]
     std = 1.0 / math.sqrt(max(1, fan_in))
-    return (jax.random.normal(key, spec.shape, jnp.float32) * std).astype(dtype)
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
 def materialize_tree(tree, key, shardings=None):
     """Instantiate an ArraySpec tree with deterministic per-leaf RNG.
 
-    With ``shardings`` (a matching tree of ``Sharding``s) each leaf is made
-    by one jitted program straight into its sharding, so no device ever
-    holds a whole sharded leaf or its f32 draw (the jitted draw rounds
-    differently from the eager one, so the values differ)."""
+    Each leaf is made by one jitted program, so a seed gives the same
+    weights on every mesh. With ``shardings`` (a matching tree of
+    ``Sharding``s) the program writes straight into the leaf's sharding, so
+    no device ever holds a whole sharded leaf or its f32 draw."""
     leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_spec)
     keys = jax.random.split(key, max(1, len(leaves)))
     if shardings is None:
-        out = [_init_leaf(s, k) for s, k in zip(leaves, keys)]
+        shs = [None] * len(leaves)
     else:
         shs = treedef.flatten_up_to(shardings)
-        out = [
-            jax.jit(functools.partial(_init_leaf, s), out_shardings=sh)(k)
-            for s, k, sh in zip(leaves, keys, shs)
-        ]
+    out = []
+    for s, k, sh in zip(leaves, keys, shs):
+        placed = {} if sh is None else {"out_shardings": sh}
+        init = jax.jit(_init_leaf, static_argnums=(0, 1, 2), **placed)
+        out.append(init(s.shape, s.dtype, s.init, k))
     return jax.tree.unflatten(treedef, out)

@@ -16,21 +16,30 @@ from repro.core.tuner import Tuner
 
 
 @pytest.mark.parametrize(
-    "backend, kw, tol",
+    "backend, seed, x_shape, w_shape, kw, atol, rtol",
     [
-        ("xla", {}, 1e-6),
-        ("pallas_interpret", dict(policy=ALL_SK, cfg=TileConfig(8, 128, 128)), 1e-4),
+        # M=21, N=16: not a multiple of any tile
+        ("xla", 0, (3, 7, 32), (32, 16), {}, 0.0, 1e-6),
+        (
+            "pallas_interpret",
+            1,
+            (16, 64),
+            (64, 128),
+            dict(policy=ALL_SK, cfg=TileConfig(8, 128, 128)),
+            1e-4,
+            1e-4,
+        ),
     ],
     ids=["xla", "pallas_interpret"],
 )
-def test_gemm_matches_dot(backend, kw, tol):
-    r = np.random.default_rng(0)
-    x = jnp.asarray(r.normal(size=(2, 8, 64)), jnp.float32)
-    w = jnp.asarray(r.normal(size=(64, 128)), jnp.float32)
+def test_gemm_matches_dot(backend, seed, x_shape, w_shape, kw, atol, rtol):
+    r = np.random.default_rng(seed)
+    x = jnp.asarray(r.normal(size=x_shape), jnp.float32)
+    w = jnp.asarray(r.normal(size=w_shape), jnp.float32)
     with gemm_context(selector=default_selector(), backend=backend):
         got = gemm(x, w, **kw)
     want = jnp.dot(x, w)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=rtol, atol=atol)
 
 
 def test_default_backend_follows_the_platform():
