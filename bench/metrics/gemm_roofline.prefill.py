@@ -1,0 +1,8 @@
+"""Kernels: least time of the Pallas GEMMs of the traced steps that carry a
+prefill chunk over the device time of their Pallas kernels (percent)."""
+
+from bench import measure
+
+
+def read(record):
+    return measure.gemm_roofline(record, chunk=True)

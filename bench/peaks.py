@@ -1,0 +1,24 @@
+"""Published peaks of the chips the benchmark runs on, keyed by JAX's
+``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture page):
+197 TFLOP/s bf16, 394 TOP/s int8, 16 GiB HBM at 819 GB/s per chip. A kind
+that is not in the table is an error, never a default.
+"""
+
+from __future__ import annotations
+
+from typing import Dict
+
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 394e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: {sorted(PEAKS)}"
+        ) from None
