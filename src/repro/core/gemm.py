@@ -48,6 +48,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import re
 import threading
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -68,6 +69,7 @@ from repro.core.quant import (
 from repro.core.selector import KernelSelector, Selection, default_selector
 from repro.core.tuner import LEGACY_GRID
 from repro.dist.sharding import current_plan
+from repro.utils.timing import span
 
 _state = threading.local()
 
@@ -127,7 +129,7 @@ def get_backend(name: str) -> BackendFn:
 
 def _xla_backend(
     x, w, *, op: GemmOp, policy, cfg, g, bias, operand, scale=None,
-    scale_a=None, b_bits=8,
+    scale_a=None, b_bits=8, tag="",
 ):
     if b_bits == 4:
         # packed int4 weights: unpack to int8 and drop the odd-K pad row
@@ -165,7 +167,7 @@ def _xla_backend(
 def _make_pallas_backend(interpret: bool) -> BackendFn:
     def backend(
         x, w, *, op: GemmOp, policy, cfg, g, bias, operand, scale=None,
-        scale_a=None, b_bits=8,
+        scale_a=None, b_bits=8, tag="",
     ):
         from repro.kernels.common import record_launch
         from repro.kernels.streamk import ops as sk_ops
@@ -191,6 +193,7 @@ def _make_pallas_backend(interpret: bool) -> BackendFn:
                 scale=scale,
                 scale_a=scale_a,
                 b_bits=b_bits,
+                tag=tag,
             )
 
         # Loop form: one pallas_call per group, so trace cost grows with G
@@ -218,6 +221,7 @@ def _make_pallas_backend(interpret: bool) -> BackendFn:
                     scale=None if scale is None else scale[i],
                     scale_a=None if scale_a is None else scale_a[i],
                     b_bits=b_bits,
+                    tag=tag,
                 )
             )
         return jnp.stack(outs)
@@ -290,6 +294,8 @@ class GemmContext:
     selector: KernelSelector
     backend: str = field(default_factory=platform_backend)
     log: List[SelectionLogEntry] = field(default_factory=list)
+    #: name each kernel after its GEMM's tag (see :func:`tagged_kernels`)
+    kernel_tags: bool = False
 
 
 def _ctx() -> GemmContext:
@@ -324,6 +330,22 @@ def gemm_context(
         _state.ctx = old
 
 
+@contextmanager
+def tagged_kernels(on: bool = True):
+    """Within this scope (with ``on``) the dispatcher puts each GEMM's tag in
+    front of its kernels' names (``[A-Za-z0-9_]`` only), so that a profile
+    tells the GEMMs of one shape apart. Each tag then makes a kernel of its
+    own, traced and compiled apart: use it for a program compiled once (a
+    jitted step), not for one lowered anew on every call."""
+    ctx = _ctx()
+    old = ctx.kernel_tags
+    ctx.kernel_tags = on
+    try:
+        yield
+    finally:
+        ctx.kernel_tags = old
+
+
 def current_log() -> List[SelectionLogEntry]:
     """The active context's selection log (created on first use)."""
     return _ctx().log
@@ -355,21 +377,27 @@ def _dispatch(
     b_bits: int = 8,
 ) -> jax.Array:
     ctx = _ctx()
-    if policy is None and cfg is None and g is None:
-        sel = ctx.selector.select_op(op)
-    elif policy is not None and cfg is not None:
-        sel = ctx.selector.record_forced(
-            op, policy, cfg, g=g if g is not None else LEGACY_GRID
-        )
-    else:
-        # partial override: fill the missing parts from selection, but log
-        # what actually runs (source "forced") — never the selector's own
-        # pick, which may pair a different policy with this cfg/g
-        sel = ctx.selector.select_partial(op, policy, cfg, g=g)
+    with span("gemm.select", None, tag=tag) as sp:
+        if policy is None and cfg is None and g is None:
+            sel = ctx.selector.select_op(op)
+        elif policy is not None and cfg is not None:
+            sel = ctx.selector.record_forced(
+                op, policy, cfg, g=g if g is not None else LEGACY_GRID
+            )
+        else:
+            # partial override: fill the missing parts from selection, but
+            # log what actually runs (source "forced") — never the
+            # selector's own pick, which may pair a different policy with
+            # this cfg/g
+            sel = ctx.selector.select_partial(op, policy, cfg, g=g)
+        sp.annotate(source=sel.source)
+    ctx.selector.stats.select_s += sp.seconds
     ctx.log.append(SelectionLogEntry(op, sel, tag))
     static = dict(policy=sel.policy, cfg=sel.cfg, g=sel.g)
     if b_bits != 8:
         static["b_bits"] = b_bits
+    if ctx.kernel_tags and tag:
+        static["tag"] = re.sub(r"[^A-Za-z0-9_]", "_", tag)
     # only quantized ops pass the dequant operands: backends registered
     # against the pre-quantization BackendFn signature keep serving dense
     # traffic unchanged, and a quantized dispatch through one fails loudly

@@ -54,7 +54,6 @@ class GossipStats:
     """Lifetime counters of one :class:`GossipExchange` (observability)."""
 
     rounds: int = 0  # exchange() calls
-    polls: int = 0  # individual shard polls across rounds
     entries: int = 0  # journal entries applied from siblings
     swaps: int = 0  # hot_swaps installed (rounds that found news)
     load_errors: int = 0  # malformed lines + unknown-tag skips observed
@@ -154,7 +153,6 @@ class GossipExchange:
         (future producers) skip-and-count, mirroring ``replay_journal``."""
         staged: Optional[TuningDatabase] = None
         for tail in self.tails:
-            self.stats.polls += 1
             before = tail.load_errors
             for entry in tail.poll():
                 if staged is None:

@@ -53,7 +53,6 @@ def _as_key_bytes(key, arch: str = DEFAULT_ARCH) -> bytes:
 class QueryStats:
     """Counters backing the paper's elimination-rate claim."""
 
-    queries: int = 0
     candidate_evals: int = 0  # policy evaluations NOT pruned
     pruned_evals: int = 0  # policy evaluations skipped thanks to the filters
 
@@ -127,7 +126,6 @@ class OpenSieve:
             out = self._query(key, arch)
             if out:
                 break
-        self.stats.queries += 1
         self.stats.candidate_evals += len(out)
         self.stats.pruned_evals += len(self.policies) - len(out)
         return out

@@ -102,6 +102,9 @@ class SelectorStats:
     forced: int = 0  # caller-supplied (policy, cfg) overrides
     evals: int = 0
     pruned: int = 0  # policies genuinely eliminated by Bloom filters
+    #: host seconds the dispatcher spent in selection (lookups, forced and
+    #: partial overrides alike)
+    select_s: float = 0.0
 
     @property
     def elimination_rate(self) -> float:

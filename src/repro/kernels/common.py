@@ -16,6 +16,14 @@ from repro.core.workpart import cdiv
 _launch_log: Optional[List[str]] = None
 
 
+def kernel_name(base: str, tag: str = "") -> str:
+    """A ``pallas_call`` name: the kernel's own (which names its tile),
+    after the dispatcher's GEMM tag when one is given, so that a profile
+    tells the GEMMs of one shape apart (``mlp_gate__dp_gemm_64x128x256``).
+    Kernels of one shape and tile under two tags are two kernels."""
+    return f"{tag}__{base}" if tag else base
+
+
 def record_launch(name: str) -> None:
     """Note one ``pallas_call`` built by a kernel wrapper.
 

@@ -31,6 +31,7 @@ from repro.core.workpart import cdiv
 from repro.core.quant import unpack_int4
 from repro.kernels.common import (
     apply_epilogue,
+    kernel_name,
     mixed_dot,
     record_launch,
 )
@@ -103,6 +104,7 @@ def dp_gemm_region(
     scale_a=None,
     b_bits: int = 8,
     g: int = 0,
+    tag: str = "",
 ):
     """Tiled GEMM over output tiles [tile_offset, m_tiles*n_tiles).
 
@@ -122,7 +124,8 @@ def dp_gemm_region(
     index maps clamp to it, so every write is the same deterministic value).
     This makes wave quantization — what the cost model scores ``g`` on — a
     real property of the launched grid. ``g`` == 0 keeps the exact legacy
-    one-program-per-tile grid.
+    one-program-per-tile grid. ``tag`` goes in front of the kernel's name
+    (:func:`repro.kernels.common.kernel_name`).
 
     Cost of padding: up to ``g - 1`` redundant tile recomputes, and the
     padded tile dim drops to sequential (ARBITRARY) semantics because the
@@ -203,7 +206,7 @@ def dp_gemm_region(
             scratch_shapes=scratch,
             interpret=interpret,
             compiler_params=params,
-            name=f"dp_gemm_{cfg.name}",
+            name=kernel_name(f"dp_gemm_{cfg.name}", tag),
         )(*operands)
 
     assert c_init is not None, "tile_offset > 0 requires c_init"
@@ -219,5 +222,5 @@ def dp_gemm_region(
         input_output_aliases={len(operands) - 1: 0},
         interpret=interpret,
         compiler_params=params,
-        name=f"dp_gemm_region_{cfg.name}",
+        name=kernel_name(f"dp_gemm_region_{cfg.name}", tag),
     )(*operands)

@@ -62,6 +62,7 @@ from repro.core.quant import unpack_int4
 from repro.core.workpart import cdiv
 from repro.kernels.common import (
     apply_epilogue,
+    kernel_name,
     mixed_dot,
     pad_to,
     record_launch,
@@ -210,6 +211,7 @@ def _fused_call(
     scale,
     scale_a,
     b_bits: int = 8,
+    tag: str = "",
 ):
     """Build and issue THE single ``pallas_call`` over the concatenated tile
     space. ``tab``: (R,) int32 row-block -> group table (scalar-prefetched);
@@ -265,7 +267,7 @@ def _fused_call(
         # Both dims sequential: the accumulator carry across workgroup
         # boundaries is only sound under a strict flattened execution order.
         semantics = (pltpu.ARBITRARY, pltpu.ARBITRARY)
-        name = f"grouped_sk_{cfg.name}_g{g}"
+        name = kernel_name(f"grouped_sk_{cfg.name}_g{g}", tag)
     else:
         n_prog = cdiv(n_tiles, g) * g if g > 0 else n_tiles
         grid = (n_prog, ipt)
@@ -306,7 +308,7 @@ def _fused_call(
         )
         tile_sem = pltpu.ARBITRARY if n_prog != n_tiles else pltpu.PARALLEL
         semantics = (tile_sem, pltpu.ARBITRARY)
-        name = f"grouped_dp_{cfg.name}"
+        name = kernel_name(f"grouped_dp_{cfg.name}", tag)
 
     operands = [a_cat, b_pad]
     in_specs = [
@@ -350,7 +352,7 @@ def _fused_call(
     jax.jit,
     static_argnames=(
         "policy", "cfg", "g", "interpret", "out_dtype", "epilogue",
-        "group_sizes", "b_bits",
+        "group_sizes", "b_bits", "tag",
     ),
 )
 def gemm_grouped_streamk(
@@ -369,6 +371,7 @@ def gemm_grouped_streamk(
     scale_a: Optional[jax.Array] = None,
     group_sizes: Optional[Tuple[int, ...]] = None,
     b_bits: int = 8,
+    tag: str = "",
 ) -> jax.Array:
     """Batched-by-expert GEMM ``c[i] = a[i] @ b[i]`` in ONE ``pallas_call``.
 
@@ -384,7 +387,8 @@ def gemm_grouped_streamk(
     stages. ``b_bits == 4``: ``b`` is int4-packed (G, ceil(K/2), N), each
     kernel block unpacked in the prologue. Accumulation is f32; policies
     other than DP run the Stream-K persistent form (HYBRID degenerates to
-    ALL_SK — one launch admits no separate DP region).
+    ALL_SK — one launch admits no separate DP region). ``tag`` goes in
+    front of the kernel's name (:func:`repro.kernels.common.kernel_name`).
     """
     if a.ndim != 3 or b.ndim != 3 or a.shape[0] != b.shape[0]:
         raise ValueError(f"bad grouped operands {a.shape} @ {b.shape}")
@@ -477,6 +481,7 @@ def gemm_grouped_streamk(
         scale=scalep,
         scale_a=scale_ap,
         b_bits=b_bits,
+        tag=tag,
     )
 
     # Scatter concatenated rows back to the dense (G, M, N) layout; padding

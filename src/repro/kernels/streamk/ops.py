@@ -48,6 +48,7 @@ def _scatter_sk_tiles(sk_tiles_out, part, out_dtype, interpret):
     jax.jit,
     static_argnames=(
         "policy", "cfg", "g", "interpret", "out_dtype", "epilogue", "b_bits",
+        "tag",
     ),
 )
 def gemm(
@@ -65,6 +66,7 @@ def gemm(
     scale: jax.Array = None,
     scale_a: jax.Array = None,
     b_bits: int = 8,
+    tag: str = "",
 ) -> jax.Array:
     """``a @ b`` under a Stream-K++ scheduling policy, with an optional fused
     epilogue (Composable-Kernel style: applied post-accumulation in the
@@ -82,7 +84,8 @@ def gemm(
     ``s_a (x) s_b`` on the f32 accumulator. ``b_bits == 4``: ``b`` is
     int4-packed (ceil(K/2), N) — K comes from ``a``, and every kernel
     unpacks its packed block in the prologue (B HBM traffic is 0.5
-    bytes/element).
+    bytes/element). ``tag`` goes in front of every kernel's name
+    (:func:`repro.kernels.common.kernel_name`).
     """
     if a.ndim != 2 or b.ndim != 2:
         raise ValueError(f"bad gemm operands {a.shape} @ {b.shape}")
@@ -116,13 +119,13 @@ def gemm(
         # the selected grid size
         cp = dp_gemm_region(
             ap, bp, cfg, out_dtype=out_dtype, interpret=interpret, g=g,
-            b_bits=b_bits, **epi,
+            b_bits=b_bits, tag=tag, **epi,
         )
         return unpad(cp, (m, n))
 
-    partials = streamk_phase1(ap, bp, part, interpret=interpret, b_bits=b_bits)
+    partials = streamk_phase1(ap, bp, part, interpret=interpret, b_bits=b_bits, tag=tag)
     sk_c = streamk_fixup(
-        partials, part, out_dtype, interpret=interpret, **epi
+        partials, part, out_dtype, interpret=interpret, tag=tag, **epi
     )
     c_sk = _scatter_sk_tiles(sk_c, part, out_dtype, interpret)
 
@@ -139,6 +142,7 @@ def gemm(
         interpret=interpret,
         g=g,
         b_bits=b_bits,
+        tag=tag,
         **epi,
     )
     return unpad(cp, (m, n))

@@ -49,6 +49,7 @@ from repro.core.quant import unpack_int4
 from repro.core.workpart import Partition, cdiv
 from repro.kernels.common import (
     apply_epilogue,
+    kernel_name,
     mixed_dot,
     record_launch,
 )
@@ -119,13 +120,16 @@ def _sk_block_indices(x, j, part: Partition):
     return tile, slot
 
 
-def streamk_phase1(a, b, part: Partition, *, interpret: bool = False, b_bits: int = 8):
+def streamk_phase1(
+    a, b, part: Partition, *, interpret: bool = False, b_bits: int = 8, tag: str = ""
+):
     """Run the Stream-K sweep; returns partials[sk_tiles, mc+1, bm, bn] f32.
 
     ``a``/``b`` must already be padded to tile multiples. ``b_bits == 4``:
     ``b`` is int4-packed (Kp/2, Np), padded to ``bk/2`` multiples, and the
     kernel unpacks each block in its prologue (the packed k-block count
     equals the logical one for even bk, so the index maps are unchanged).
+    ``tag`` goes in front of the kernel's name (``kernel_name``).
     """
     cfg = part.cfg
     ipt, total, ipw, mc = _range_math(part)
@@ -166,7 +170,7 @@ def streamk_phase1(a, b, part: Partition, *, interpret: bool = False, b_bits: in
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY),
         ),
-        name=f"streamk_p1_{cfg.name}_g{part.g}",
+        name=kernel_name(f"streamk_p1_{cfg.name}_g{part.g}", tag),
     )(a, b)
 
 
@@ -219,6 +223,7 @@ def _fixup_kernel(
 def streamk_fixup(
     partials, part: Partition, out_dtype, *, interpret: bool = False,
     epilogue="none", bias=None, operand=None, scale=None, scale_a=None,
+    tag: str = "",
 ):
     """Reduce contributor slots per SK tile -> C tiles, shaped
     (sk_tiles, bm, bn). The epilogue (activation, bias-add, swiglu-mul /
@@ -228,7 +233,8 @@ def streamk_fixup(
     accumulator first — together the rank-1 rescale of an int8xint8 op (see
     ``apply_epilogue``). ``bias`` (1, Np) / ``operand`` (Mp, Np) are padded
     full-size arrays; their blocks are gathered per SK tile in row-major
-    tile order (matching ``_scatter_sk_tiles``)."""
+    tile order (matching ``_scatter_sk_tiles``). ``tag`` goes in front of
+    the kernel's name (``kernel_name``)."""
     cfg = part.cfg
     nt = part.n_tiles
     kernel = functools.partial(
@@ -273,5 +279,5 @@ def streamk_fixup(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,),
         ),
-        name=f"streamk_fixup_{cfg.name}",
+        name=kernel_name(f"streamk_fixup_{cfg.name}", tag),
     )(*operands)

@@ -639,6 +639,24 @@ def make_engine(args, model, params, adaptive=None):
     )
 
 
+def phase_totals(m) -> str:
+    """One line of a paged engine's ``metrics()``: host seconds and count of
+    each span name, the compiles and compile-cache loads charged to it, and
+    the decode rows per decode batch."""
+    parts = []
+    for name in sorted(k[: -len(".count")] for k in m if k.endswith(".count")):
+        part = f"{name} {m[name + '.s']:.3f} s/{m[name + '.count']}"
+        if m[name + ".compiles"] or m[name + ".cache_loads"]:
+            part += (
+                f" ({m[name + '.compiles']} compiles, {m[name + '.cache_loads']} "
+                f"cache loads {m[name + '.cache_load_s']:.3f} s)"
+            )
+        parts.append(part)
+    rows = m["decode_rows"] / m["decode_ticks"] if m["decode_ticks"] else 0.0
+    parts.append(f"{rows:.2f} rows per decode batch")
+    return ", ".join(parts)
+
+
 def main(argv=None) -> int:
     args = parse_args(argv)
     enable_compile_cache()
@@ -765,6 +783,12 @@ def main(argv=None) -> int:
                 m["rejected"],
                 m["truncated"],
                 m["stall_events"],
+            )
+            log.info(
+                "worker %d phases: %s; selection %.3f s",
+                w,
+                phase_totals(m),
+                engine.dispatch_stats.select_s,
             )
         if args.replay != "off" and done:
             lat = sorted(r.done_step - r.submit_step for r in done)

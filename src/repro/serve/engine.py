@@ -30,6 +30,7 @@ from repro.core.gemm import current_log, current_selector, gemm_context
 from repro.core.selector import KernelSelector, SelectorStats
 from repro.dist.sharding import current_plan
 from repro.utils.logging import get_logger
+from repro.utils.timing import SpanStats, span
 
 log = get_logger("serve")
 
@@ -171,6 +172,9 @@ class EngineCore:
             selector = adaptive.selector
         self.adaptive = adaptive
         self.adapt_every = adapt_every
+        # host seconds, counts and compiles of each span name the engine
+        # opens (see ``repro.utils.timing.span``)
+        self.counters: Dict[str, SpanStats] = {}
         self._steps = 0
         self._max_seq = max_seq
         # Dispatch threading: when the caller hands the engine a selector
@@ -276,7 +280,8 @@ class EngineCore:
             and self.adapt_every > 0
             and self._steps % self.adapt_every == 0
         ):
-            self.adaptive.adapt()
+            with span("engine.adapt", self.counters):
+                self.adaptive.adapt()
 
     # -- drain loop --------------------------------------------------------
     def step(self) -> bool:  # pragma: no cover - abstract

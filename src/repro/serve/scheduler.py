@@ -41,10 +41,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.adaptive import AdaptiveTuner
+from repro.core.gemm import tagged_kernels
 from repro.core.selector import KernelSelector
 from repro.serve.engine import EngineCore, Request
 from repro.serve.paged_kv import PagedKVCache, PageTable
 from repro.utils.logging import get_logger
+from repro.utils.timing import span
 
 log = get_logger("serve.paged")
 
@@ -72,7 +74,8 @@ class PagedServeConfig:
 
 @dataclass
 class PagedRequest(Request):
-    """Request + paged lifecycle state + SLO timestamps."""
+    """Request + paged lifecycle state + SLO timestamps (``*_wall`` on
+    ``time.perf_counter``, the clock of the engine's span counters)."""
 
     table: PageTable = field(default_factory=PageTable)
     prefilled: int = 0  # prompt tokens already prefilled
@@ -82,6 +85,7 @@ class PagedRequest(Request):
     first_token_step: int = -1
     done_step: int = -1
     submit_wall: float = 0.0
+    admit_wall: float = 0.0
     first_token_wall: float = 0.0
     done_wall: float = 0.0
 
@@ -126,6 +130,12 @@ class PagedServeEngine(EngineCore):
         self.truncated = 0  # anti-deadlock early retirements
         self.stall_events = 0  # decode ticks skipped for want of a page
         self.peak_resident = 0
+        self.decode_ticks = 0  # decode batches run
+        self.decode_rows = 0  # rows that decoded a token, over those batches
+        # name each kernel of the jitted decode and chunk steps after its
+        # GEMM's tag, for a profile; set before the first step. Off by
+        # default: a kernel per tag traces and compiles more at set-up.
+        self.tag_kernels = False
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._chunk_step = jax.jit(self._chunk_impl, donate_argnums=(1,))
 
@@ -134,9 +144,10 @@ class PagedServeEngine(EngineCore):
         """gather view -> unchanged model.decode_step -> scatter the one new
         row per sequence back into its page."""
         view = self.kv.gather_view(pool, pages_2d)
-        logits, new_view = self.model.decode_step(
-            params, view, tokens, pos, div=self.div
-        )
+        with tagged_kernels(self.tag_kernels):
+            logits, new_view = self.model.decode_step(
+                params, view, tokens, pos, div=self.div
+            )
         rows = self.kv.rows_at(new_view, pos)
         b = pos.shape[0]
         pg = pages_2d[jnp.arange(b), pos // self.kv.page_size]
@@ -147,9 +158,10 @@ class PagedServeEngine(EngineCore):
         """One prompt chunk for one sequence (B == 1): gather its pages,
         run model.prefill_chunk, scatter the chunk's rows back."""
         view = self.kv.gather_view(pool, pages_2d)
-        logits, new_view = self.model.prefill_chunk(
-            params, view, chunk, start, div=self.div
-        )
+        with tagged_kernels(self.tag_kernels):
+            logits, new_view = self.model.prefill_chunk(
+                params, view, chunk, start, div=self.div
+            )
         c = chunk.shape[1]
         pos_block = start[0] + jnp.arange(c)  # (C,)
         rows = jax.tree.map(lambda a: a[:, 0, pos_block], new_view)
@@ -186,7 +198,7 @@ class PagedServeEngine(EngineCore):
         self._uid += 1
         req = PagedRequest(self._uid, prompt, max_new_tokens, temperature)
         req.submit_step = self._steps
-        req.submit_wall = time.monotonic()
+        req.submit_wall = time.perf_counter()
         self._queue.append(req)
         return self._uid
 
@@ -200,17 +212,20 @@ class PagedServeEngine(EngineCore):
         over. No skipping ahead (a younger short request must not starve an
         older long one) and no eviction."""
         n = 0
-        while self._queue and len(self.active) < self.cfg.max_active:
-            head = self._queue[0]
-            need = self.kv.pages_for(len(head.prompt))
-            if self.kv.free_pages - need < self.cfg.reserve_pages:
-                break
-            self._queue.pop(0)
-            head.table = PageTable(self.kv.alloc(need), 0)
-            self.active.append(head)
-            self.admitted += 1
-            n += 1
-        self.peak_resident = max(self.peak_resident, len(self.active))
+        with span("engine.admit", self.counters) as sp:
+            while self._queue and len(self.active) < self.cfg.max_active:
+                head = self._queue[0]
+                need = self.kv.pages_for(len(head.prompt))
+                if self.kv.free_pages - need < self.cfg.reserve_pages:
+                    break
+                self._queue.pop(0)
+                head.table = PageTable(self.kv.alloc(need), 0)
+                head.admit_wall = time.perf_counter()
+                self.active.append(head)
+                self.admitted += 1
+                n += 1
+            self.peak_resident = max(self.peak_resident, len(self.active))
+            sp.annotate(admitted=n)
         return n
 
     # -- prefill -----------------------------------------------------------
@@ -232,58 +247,53 @@ class PagedServeEngine(EngineCore):
             chunk = min(self.cfg.prefill_chunk, remaining)
         start = req.prefilled
         tokens = jnp.asarray(req.prompt[start : start + chunk])[None, :]
-        cap = req.table.capacity * self.kv.page_size
         with self._dispatch_ctx():
-            if start == 0 and chunk == len(req.prompt):
-                # whole-prompt fast path: the same model.prefill call (and
-                # the same numerics) as the dense engine, scattered into
-                # this sequence's pages instead of a slot stripe
-                logits, fresh = self.model.prefill(
-                    self.params, tokens, max_seq=cap, div=self.div
-                )
-                self.kv.pool = self.kv.scatter_prefill(
-                    self.kv.pool, jnp.asarray(req.table.pages, jnp.int32), fresh
-                )
-            elif start == 0:
-                # first chunk: no prefix to attend over; prefill at the
-                # chunk length and scatter its pages' worth of rows
-                logits, fresh = self.model.prefill(
-                    self.params,
-                    tokens,
-                    max_seq=self.kv.pages_for(chunk) * self.kv.page_size,
-                    div=self.div,
-                )
-                pages = req.table.pages[: self.kv.pages_for(chunk)]
-                self.kv.pool = self.kv.scatter_prefill(
-                    self.kv.pool, jnp.asarray(pages, jnp.int32), fresh
-                )
+            if start == 0:
+                # the first chunk (or the whole prompt: the dense engine's
+                # model.prefill call and numerics) runs outside jit with no
+                # prefix to attend over, at its own pages' length, and
+                # scatters the rows into this sequence's pages
+                n_pages = self.kv.pages_for(chunk)
+                with span("engine.prefill.first", self.counters, uid=req.uid, tokens=chunk):
+                    logits, fresh = self.model.prefill(
+                        self.params,
+                        tokens,
+                        max_seq=n_pages * self.kv.page_size,
+                        div=self.div,
+                    )
+                    self.kv.pool = self.kv.scatter_prefill(
+                        self.kv.pool,
+                        jnp.asarray(req.table.pages[:n_pages], jnp.int32),
+                        fresh,
+                    )
             else:
-                pages_2d = self.kv.padded_tables([req.table])
-                logits, self.kv.pool = self._chunk_step(
-                    self.params,
-                    self.kv.pool,
-                    pages_2d,
-                    tokens,
-                    jnp.asarray([start], jnp.int32),
-                )
+                with span("engine.prefill.chunk", self.counters, uid=req.uid, start=start, size=chunk):
+                    logits, self.kv.pool = self._chunk_step(
+                        self.params,
+                        self.kv.pool,
+                        self.kv.padded_tables([req.table]),
+                        tokens,
+                        jnp.asarray([start], jnp.int32),
+                    )
         req.prefilled += chunk
         req.table.length = req.prefilled
         if req.prefilled < len(req.prompt):
             return True
         # prompt complete: sample the first token (same contract as the
         # dense engine's _prefill_slot)
-        req.pos = len(req.prompt)
-        tok = self._sample(np.asarray(logits)[0, -1], req.temperature)
-        req.out_tokens.append(int(tok))
-        req.first_token_step = self._steps
-        req.first_token_wall = time.monotonic()
-        full = req.pos >= self.cfg.max_seq
-        if (
-            tok == self.cfg.eos
-            or len(req.out_tokens) >= req.max_new_tokens
-            or full
-        ):
-            self._retire(req)
+        with span("engine.sample", self.counters, rows=1):
+            req.pos = len(req.prompt)
+            tok = self._sample(np.asarray(logits)[0, -1], req.temperature)
+            req.out_tokens.append(int(tok))
+            req.first_token_step = self._steps
+            req.first_token_wall = time.perf_counter()
+            full = req.pos >= self.cfg.max_seq
+            if (
+                tok == self.cfg.eos
+                or len(req.out_tokens) >= req.max_new_tokens
+                or full
+            ):
+                self._retire(req)
         return True
 
     # -- decode ------------------------------------------------------------
@@ -310,49 +320,52 @@ class PagedServeEngine(EngineCore):
         return True
 
     def _decode_tick(self) -> bool:
-        cand = self._decode_candidates()
-        runnable = [r for r in cand if self._ensure_page(r)]
-        if not runnable:
-            return False
-        b = self.cfg.max_active
-        runnable = runnable[:b]
-        tokens = np.zeros((b, 1), np.int32)
-        pos = np.zeros((b,), np.int32)
-        tables = []
-        for i, r in enumerate(runnable):
-            tokens[i, 0] = r.out_tokens[-1]
-            pos[i] = r.pos
-            tables.append(r.table)
-        # pad the batch to the fixed decode width with scratch-page rows
-        tables.extend(PageTable() for _ in range(b - len(runnable)))
-        pages_2d = self.kv.padded_tables(tables)
-        with self._dispatch_ctx():
+        with span("engine.decode.prepare", self.counters) as sp:
+            cand = self._decode_candidates()
+            runnable = [r for r in cand if self._ensure_page(r)]
+            if not runnable:
+                return False
+            b = self.cfg.max_active
+            runnable = runnable[:b]
+            sp.annotate(rows=len(runnable))
+            tokens = np.zeros((b, 1), np.int32)
+            pos = np.zeros((b,), np.int32)
+            tables = []
+            for i, r in enumerate(runnable):
+                tokens[i, 0] = r.out_tokens[-1]
+                pos[i] = r.pos
+                tables.append(r.table)
+            # pad the batch to the fixed decode width with scratch-page rows
+            tables.extend(PageTable() for _ in range(b - len(runnable)))
+            pages_2d = self.kv.padded_tables(tables)
+            tokens, pos = jnp.asarray(tokens), jnp.asarray(pos)
+        with span("engine.decode.dispatch", self.counters), self._dispatch_ctx():
             logits, self.kv.pool = self._decode(
-                self.params,
-                self.kv.pool,
-                pages_2d,
-                jnp.asarray(tokens),
-                jnp.asarray(pos),
+                self.params, self.kv.pool, pages_2d, tokens, pos
             )
-        logits_np = np.asarray(logits)[:, 0]
-        for i, req in enumerate(runnable):
-            req.pos += 1
-            req.table.length = req.pos
-            tok = self._sample(logits_np[i], req.temperature)
-            req.out_tokens.append(tok)
-            if (
-                tok == self.cfg.eos
-                or len(req.out_tokens) >= req.max_new_tokens
-                or req.pos >= self.cfg.max_seq
-            ):
-                self._retire(req)
+        with span("engine.decode.wait", self.counters):
+            logits_np = np.asarray(logits)[:, 0]
+        self.decode_ticks += 1
+        self.decode_rows += len(runnable)
+        with span("engine.sample", self.counters, rows=len(runnable)):
+            for i, req in enumerate(runnable):
+                req.pos += 1
+                req.table.length = req.pos
+                tok = self._sample(logits_np[i], req.temperature)
+                req.out_tokens.append(tok)
+                if (
+                    tok == self.cfg.eos
+                    or len(req.out_tokens) >= req.max_new_tokens
+                    or req.pos >= self.cfg.max_seq
+                ):
+                    self._retire(req)
         return True
 
     def _retire(self, req: PagedRequest, truncated: bool = False):
         req.done = True
         req.truncated = truncated
         req.done_step = self._steps
-        req.done_wall = time.monotonic()
+        req.done_wall = time.perf_counter()
         if truncated and req.first_token_wall == 0.0:
             req.first_token_step = self._steps
             req.first_token_wall = req.done_wall
@@ -362,6 +375,10 @@ class PagedServeEngine(EngineCore):
 
     # -- one scheduling quantum --------------------------------------------
     def step(self) -> bool:
+        with span("engine.step", self.counters, step_num=self._steps):
+            return self._step()
+
+    def _step(self) -> bool:
         progress = 0
         if self.cfg.prefill_chunk > 0:
             # chunked mode: ONE bounded prefill quantum per step — long
@@ -404,6 +421,9 @@ class PagedServeEngine(EngineCore):
 
     # -- observability -----------------------------------------------------
     def metrics(self) -> Dict[str, float]:
+        """Pool occupancy, admission counters, decode batches, and per span
+        name its count, host seconds (``.s``), compiles, compile-cache loads
+        and their seconds."""
         occ = self.kv.occupancy()
         occ.update(
             admitted=self.admitted,
@@ -414,5 +434,15 @@ class PagedServeEngine(EngineCore):
             resident=len(self.active),
             queued=len(self._queue),
             steps=self._steps,
+            decode_ticks=self.decode_ticks,
+            decode_rows=self.decode_rows,
         )
+        for name, st in self.counters.items():
+            occ.update({
+                f"{name}.count": st.count,
+                f"{name}.s": st.seconds,
+                f"{name}.compiles": st.compiles,
+                f"{name}.cache_loads": st.cache_loads,
+                f"{name}.cache_load_s": st.cache_load_s,
+            })
         return occ

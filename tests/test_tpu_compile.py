@@ -162,6 +162,24 @@ def test_granite_decode_step_compiles_under_pallas(one_chip, on_tpu):
     assert {e.tag for e in ctx.log} >= {"attn.q", "attn.o", "mlp.in", "mlp.out", "lm_head"}
 
 
+def test_tagged_decode_step_compiles_with_a_kernel_per_tag(one_chip, on_tpu):
+    """Under ``tagged_kernels`` (the paged engine's jitted steps, with
+    ``tag_kernels``) every Mosaic kernel of the compiled step is named by
+    its GEMM's tag and tile."""
+    model = _granite()
+
+    def shardings(specs):
+        if specs is None:
+            return one_chip, one_chip
+        return jax.tree.map(lambda _: one_chip, abstract_tree(specs))
+
+    args = _decode_args(model, shardings)
+    with gemm_mod.gemm_context(backend="pallas") as ctx, gemm_mod.tagged_kernels():
+        text = jax.jit(model.decode_step).lower(*args).compile().as_text()
+    for e in ctx.log:
+        assert f"{e.tag.replace('.', '_')}__" in text and e.selection.cfg.name in text, e.tag
+
+
 def test_tensor_parallel_decode_step_runs_each_kernel_on_its_shard(
     topo, on_tpu, monkeypatch
 ):
