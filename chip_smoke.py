@@ -218,13 +218,11 @@ class Served:
 def serve_once(args, model, params, prompts, forced=None) -> Served:
     """Serve ``prompts`` to completion through ``serve.make_engine`` under
     the ambient gemm context and plan, recording what each served program
-    returns. The first chunk of a prompt runs eagerly through
-    ``model.prefill``; later chunks through the engine's jitted chunk step;
-    decode through its jitted decode step, whose padding rows are dropped.
+    returns. Every chunk of a prompt, the first included, runs through the
+    engine's jitted chunk step; decode through its jitted decode step,
+    whose padding rows are dropped.
     With ``forced``, the engine samples those tokens instead of its own
     picks, so every step sees the inputs of the run that made them."""
-    import copy
-
     import numpy as np
 
     from repro.launch import serve
@@ -246,13 +244,6 @@ def serve_once(args, model, params, prompts, forced=None) -> Served:
     engine._decode = tap("decode", engine._decode, lambda a: np.asarray(a[4]) > 0)
     engine._chunk_step = tap("chunk", engine._chunk_step, lambda a: slice(None))
 
-    def first_chunk(*a, **kw):
-        out = model.prefill(*a, **kw)
-        steps.append((f"first chunk {tuple(a[1].shape)}", np.asarray(out[0], np.float32)))
-        return out
-
-    engine.model = copy.copy(model)
-    engine.model.prefill = first_chunk
     sample = engine._sample
 
     def pick(logits, temperature):
@@ -368,7 +359,7 @@ def serve_phase(sizes: Sizes, *, mesh_model: int, backend: str, rehearsal: bool,
     )
 
     (key, p), (_, x) = run.steps[0], xla.steps[0]
-    check(key.startswith("first chunk"), f"the first served step is {key}")
+    check(key.startswith("chunk"), f"the first served step is {key}")
     gap = rms(p - x) / rms(x)
     sig_p, sig_x = rms(p - ref) / rms(ref), rms(x - ref) / rms(ref)
     say(

@@ -248,11 +248,10 @@ class PagedServeEngine(EngineCore):
         start = req.prefilled
         tokens = jnp.asarray(req.prompt[start : start + chunk])[None, :]
         with self._dispatch_ctx():
-            if start == 0:
-                # the first chunk (or the whole prompt: the dense engine's
-                # model.prefill call and numerics) runs outside jit with no
-                # prefix to attend over, at its own pages' length, and
-                # scatters the rows into this sequence's pages
+            if self.cfg.prefill_chunk == 0:
+                # the whole prompt: the dense engine's model.prefill call and
+                # numerics, outside jit at its own pages' length, scattered
+                # into this sequence's pages
                 n_pages = self.kv.pages_for(chunk)
                 with span("engine.prefill.first", self.counters, uid=req.uid, tokens=chunk):
                     logits, fresh = self.model.prefill(
@@ -267,7 +266,14 @@ class PagedServeEngine(EngineCore):
                         fresh,
                     )
             else:
-                with span("engine.prefill.chunk", self.counters, uid=req.uid, start=start, size=chunk):
+                # every chunk, the first at start 0, through the jitted
+                # chunk step: prefill_chunk masks each key past the query's
+                # position, so the pages not written yet are never read
+                if start == 0:
+                    sp = span("engine.prefill.first", self.counters, uid=req.uid, tokens=chunk)
+                else:
+                    sp = span("engine.prefill.chunk", self.counters, uid=req.uid, start=start, size=chunk)
+                with sp:
                     logits, self.kv.pool = self._chunk_step(
                         self.params,
                         self.kv.pool,

@@ -83,13 +83,17 @@ def test_paged_tokens_identical_to_dense(served):
     assert eng.kv.used_pages == 0  # every retirement returned its pages
 
 
-def test_chunked_prefill_tokens_identical_to_dense(served):
-    """Chunked prefill (chunk size straddling page boundaries, prompts not
-    chunk-aligned) must produce the same first token and decode chain."""
+@pytest.mark.parametrize("chunk", [5, 32], ids=["straddling", "whole-prompt"])
+def test_chunked_prefill_tokens_identical_to_dense(served, chunk):
+    """Chunked prefill must produce the same first token and decode chain:
+    with a chunk that straddles page boundaries over prompts not
+    chunk-aligned, and with a chunk longer than every prompt, where the
+    jitted chunk step at start 0 alone gives the first token."""
     cfg, model, params = served
     prompts = mixed_prompts(cfg, n=4, lo=11, hi=21, seed=3)
+    assert chunk == 5 or chunk >= max(map(len, prompts))
     dense = run_dense(model, params, prompts)
-    paged, eng = run_paged(model, params, prompts, prefill_chunk=5)
+    paged, eng = run_paged(model, params, prompts, prefill_chunk=chunk)
     assert paged == dense
 
 

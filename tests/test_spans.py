@@ -121,7 +121,7 @@ def test_engine_metrics_export_every_span_and_the_decode_batches(served):
             assert f"{name}.{field}" in m
     # run() steps until a step finds nothing to do, which counts no step
     assert m["engine.step.count"] == m["steps"] + 1
-    assert m["engine.prefill.first.count"] == len(prompts)  # one eager chunk a request
+    assert m["engine.prefill.first.count"] == len(prompts)  # one first chunk a request
     chunks = sum(-(-len(p) // 6) - 1 for p in prompts)
     assert m["engine.prefill.chunk.count"] == chunks
     assert m["engine.decode.dispatch.count"] == m["engine.decode.wait.count"] == m["decode_ticks"]
@@ -129,10 +129,44 @@ def test_engine_metrics_export_every_span_and_the_decode_batches(served):
     assert m["decode_rows"] == len(prompts) * 4
     assert 1 <= m["decode_rows"] / m["decode_ticks"] <= 3
     assert m["engine.step.s"] >= m["engine.prefill.first.s"] + m["engine.decode.wait.s"]
-    # the eager first chunk compiles, or loads from a compile cache
+    # the first request's first chunk compiles the jitted chunk step at its
+    # shape, or loads it from a compile cache
     assert m["engine.prefill.first.compiles"] + m["engine.prefill.first.cache_loads"] >= 1
     assert "engine.adapt.count" not in m
     assert eng.dispatch_stats.select_s > 0
+
+
+def test_a_first_chunk_of_a_known_shape_compiles_nothing(served):
+    """The first chunk runs the jitted chunk step: a second prompt of the
+    first one's length reuses its program, with no compile or cache load."""
+    cfg, model, params = served
+    eng = _paged(model, params)
+    p1, p2 = _prompts(cfg, n=2, seed=4)
+    p2 = np.resize(p2, len(p1))
+    eng.submit(p1, max_new_tokens=2)
+    eng.run()
+    before = eng.metrics()
+    eng.submit(p2, max_new_tokens=2)
+    eng.run()
+    after = eng.metrics()
+    assert after["engine.prefill.first.count"] == before["engine.prefill.first.count"] + 1
+    for field in ("compiles", "cache_loads"):
+        assert after[f"engine.prefill.first.{field}"] == before[f"engine.prefill.first.{field}"]
+
+
+def test_chunked_mode_never_calls_the_whole_prompt_prefill(served, monkeypatch):
+    cfg, model, params = served
+
+    def whole_prompt(*a, **kw):
+        raise AssertionError("model.prefill ran in chunked mode")
+
+    monkeypatch.setattr(model, "prefill", whole_prompt)
+    eng = _paged(model, params)
+    prompts = _prompts(cfg, n=3, seed=5)
+    for p in prompts:
+        eng.submit(p, max_new_tokens=2)
+    assert len(eng.run()) == len(prompts)
+    assert eng.metrics()["engine.prefill.first.count"] == len(prompts)
 
 
 def test_request_stamps_share_the_span_clock(served):
@@ -182,7 +216,7 @@ CFG = TileConfig(16, 128, 128)
 def test_two_tags_of_one_shape_share_one_kernel(policy):
     """The tag stays out of the kernel: two GEMMs of one shape and tile
     trace to one kernel function, so a program that is lowered again for
-    every request (the eager first chunk) lowers it once."""
+    every request (whole-prompt mode's eager prefill) lowers it once."""
     x, w = jnp.ones((16, 256)), jnp.ones((256, 128))
     with gemm_mod.gemm_context(backend="pallas_interpret"):
         jaxpr = jax.make_jaxpr(
@@ -223,8 +257,9 @@ def test_tagged_kernels_hold_the_tag_and_the_tile(policy):
 
 def test_the_engines_jitted_programs_name_kernels_by_tag(served):
     """With ``tag_kernels`` the decode and chunk steps, compiled once, carry
-    each GEMM's tag; without it, and in the eager first chunk, lowered for
-    every request, a kernel is named by its shape and tile alone."""
+    each GEMM's tag; without it, and in whole-prompt mode's eager prefill,
+    lowered for every request, a kernel is named by its shape and tile
+    alone."""
     cfg, model, params = served
     eng = _paged(model, params, backend="pallas_interpret")
     kv = eng.kv
