@@ -1,17 +1,20 @@
 """What decides ``correct``: served tokens against the float32 reference.
 
 Once the window has closed, every request that the run finished goes
-through the reference: it runs once over each prompt with its served tokens,
-and for every served token reads how far that token's reference logit lies
-below the reference's best at its position (0 where the served token is the
+through the reference that the configuration names (``reference``: a module
+of ``bench/reference/``, whose contract ``bench/harness.py`` states): it
+runs once over each prompt with its served tokens, and for every served
+token reads how far that token's reference logit lies below the
+reference's best at its position (0 where the served token is the
 reference's own pick). The widest such gap over all of them is held to the
 cell's limit (``bench/cells/<workload>.json``: ``max_logit_gap``); the mean
 gap and the share of tokens that are not the reference's pick are readings.
 
 The control (``control=True``, used by ``bench/limits.py`` and the tests,
 never by a benchmark run) reads, at the same positions, the gap of the token
-that the int8 forward pass would put first; :func:`as_control` puts those
-readings in the program's place, so that the control is judged by the same
+that the control (the reference one precision lower: int8 for the bf16
+configurations) would put first; :func:`as_control` puts those readings in
+the program's place, so that the control is judged by the same
 :func:`correct`.
 """
 
@@ -32,13 +35,13 @@ class Served:
     max_new: int
 
 
-def compare(params, ref_cfg, reqs: Sequence[Served], bucket: int, control: bool = False) -> Dict:
-    """How far the served tokens' reference logits lie below the
-    reference's best: the widest gap, the mean gap, and the share of served
-    tokens that are not the reference's own pick; with ``control``, the same
-    of the int8 forward pass's picks at the same positions."""
-    from bench.reference import decoder
-
+def compare(reference, params, config: Dict, reqs: Sequence[Served], bucket: int, control: bool = False) -> Dict:
+    """How far the served tokens' logits in ``reference`` (a module of
+    ``bench/reference/``, run at ``config``) lie below its best: the widest
+    gap, the mean gap, and the share of served tokens that are not the
+    reference's own pick; with ``control``, the same of the control's picks
+    at the same positions."""
+    ref_cfg = reference.RefConfig.from_config(config)
     served, low = [], []
     short = 0
     for r in reqs:
@@ -52,7 +55,7 @@ def compare(params, ref_cfg, reqs: Sequence[Served], bucket: int, control: bool 
         nxt = np.zeros(bucket, np.int32)
         nxt[: len(seq) - 1] = seq[1:]
         nxt[len(seq) - 1] = r.tokens[-1]
-        gap, ctrl = decoder.gaps(params, toks, nxt, ref_cfg, control)
+        gap, ctrl = reference.gaps(params, toks, nxt, ref_cfg, control)
         at = slice(plen - 1, plen - 1 + n)
         served.append(np.asarray(gap)[at])
         low.append(np.asarray(ctrl)[at])

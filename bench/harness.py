@@ -5,23 +5,42 @@ Everything that belongs to one configuration, traffic mix or per-layer metric
 is a file the harness finds by name under the benchmark root (the directory
 that holds ``BENCHMARK.json``):
 
-- ``BENCHMARK.json``'s ``configs[].file``: the configuration;
+- ``BENCHMARK.json``'s ``configs[].file``: the configuration, which names
+  its reference under ``reference`` and may scale drawn weights under
+  ``weight_scale`` (below);
+- ``bench/reference/<reference>.py``: the plain reference that decides
+  ``correct``, imports nothing of the program and is given the same weight
+  arrays as the program. It has ``RefConfig.from_config(config)``, a
+  hashable (static) configuration made from the configuration file's dict,
+  and ``gaps(params, tokens, nxt, ref_cfg, control)``: over one sequence
+  ``tokens`` (S,) of prompt and served tokens, padded, and the token served
+  after each position ``nxt`` (S,), it returns two (S,) arrays: how far the
+  reference's logit of ``nxt[i]`` lies below its best at position i, and,
+  with ``control``, the same gap of the token that the control (the
+  reference one precision lower) puts first there, else zeros;
 - ``bench/traffic/<mix>.json``: the mix, whose ``generator`` names a module
   ``bench/generators/<generator>.py`` with ``make(mix, seed, vocab)``;
 - ``bench/cells/<workload>.json``: the limits that decide ``correct``;
 - ``bench/metrics/<metric>.py``: a reader ``read(record)`` per per-layer
   metric, given the :class:`Record` of the run.
 
+A configuration's ``weight_scale`` maps dotted key paths of the program's
+``param_specs()`` tree (``layers.moe.router``) to a factor on that leaf's
+drawn weights (``bench/weights.py``); each key is explained under the file's
+``assumed``. Without it every leaf is drawn as before.
+
 The loop keeps the time itself, with the host clock read after each
 ``engine.step()`` returns; it reads only the engine's public state
 (``submit``, ``step``, ``active``, ``outstanding``, each request's
-``out_tokens`` and ``prefilled``) and the dispatch context's selection log.
+``out_tokens`` and ``prefilled``, and ``metrics()`` as the window opens and
+closes) and the dispatch context's selection log.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import importlib.util
 import json
 import sys
@@ -83,6 +102,11 @@ class Cell:
         config = json.loads((root / cfg_entry["file"]).read_text())
         mix = json.loads((root / "bench" / "traffic" / f"{entry['traffic']}.json").read_text())
         limits = json.loads((root / "bench" / "cells" / f"{workload}.json").read_text())
+        if "reference" not in config:
+            raise ValueError(f"{cfg_entry['file']} names no reference (a module of bench/reference/)")
+        unexplained = set(config.get("weight_scale", {})) - set(config.get("assumed", {}))
+        if unexplained:
+            raise ValueError(f"{cfg_entry['file']}: weight_scale {sorted(unexplained)} not explained under assumed")
         if rehearsal:
             # tiny widths and lengths for a CPU rehearsal of the same code
             config = {**config, **config["rehearsal"]["config"], "engine": config["rehearsal"]["engine"]}
@@ -103,6 +127,12 @@ class Cell:
 
     def per_layer(self) -> List[Dict]:
         return [m for m in self.benchmark["per_layer"] if self.workload in m.get("workloads", [self.workload])]
+
+    @functools.cached_property
+    def reference(self):
+        """The configuration's reference module, ``bench/reference/<name>.py``."""
+        name = self.config["reference"]
+        return load_module(self.root / "bench" / "reference" / f"{name}.py", f"bench_reference_{name}")
 
     def traffic(self, seed: int):
         gen = load_module(self.root / "bench" / "generators" / f"{self.mix['generator']}.py", "bench_generator")
@@ -157,7 +187,9 @@ def build(cell: Cell, seed: int) -> Built:
     args = serve.parse_args(argv)
     model = build_model(model_config(cell))
     t0 = time.perf_counter()
-    params = weights.make(model.param_specs(), seed, lambda x: isinstance(x, ArraySpec))
+    params = weights.make(
+        model.param_specs(), seed, lambda x: isinstance(x, ArraySpec), cell.config.get("weight_scale")
+    )
     jax.block_until_ready(params)
     weights_s = time.perf_counter() - t0
     selector, _ = serve.build_worker(
@@ -208,7 +240,7 @@ def shapes(cell: Cell, traffic) -> Tuple[List[int], List[Tuple[int, int]]]:
     chunks = set()
     for n in classes:
         width = pow2(pages_for(n, page))
-        for start in range(chunk, n, chunk):
+        for start in range(0, n, chunk):
             chunks.add((min(chunk, n - start), width))
     return widths, sorted(chunks)
 
@@ -240,9 +272,10 @@ def gemm_of(entry) -> Gemm:
 
 def warm(built: Built, cell: Cell, traffic, log: List) -> Dict[str, List[Gemm]]:
     """Compile (or load from the compile cache) every program the window will
-    run: the decode step at each table width, the chunk step at each shape,
-    and one short request of each prompt length, which runs the eager first
-    chunk. Returns the GEMMs the decode step and each chunk size trace to."""
+    run: the decode step at each table width, the chunk step at each shape
+    (a request's first chunk among them), and one short request of each
+    prompt length, which runs admission and sampling as the window does.
+    Returns the GEMMs the decode step and each chunk size trace to."""
     import jax
     import jax.numpy as jnp
 
@@ -302,7 +335,6 @@ class Step:
     t1: float
     decode_ctx: List[int]  # keys attended by each row that decoded a token
     chunk: Optional[Tuple[int, int]]  # (start, size) of the prefill chunk it ran
-    eager: List[Gemm]  # GEMMs traced during the step (the eager first chunk)
     gc_s: float = 0.0  # host seconds of garbage collection inside the step
     load_s: float = 0.0  # host seconds of compile-cache loads inside the step
 
@@ -315,11 +347,10 @@ class Loop:
     """Drives the engine and records steps and request times."""
 
     def __init__(
-        self, engine, log: List, clock: Callable[[], float] = time.perf_counter,
+        self, engine, clock: Callable[[], float] = time.perf_counter,
         host: Callable[[], Tuple[float, float]] = lambda: (0.0, 0.0),
     ):
         self.engine = engine
-        self.log = log
         self.clock = clock
         self.host = host  # running host seconds of (garbage collection, compile-cache loads)
         self.tracked: Dict[int, Tracked] = {}
@@ -349,7 +380,6 @@ class Loop:
         import jax
 
         before = [(t, len(t.req.out_tokens), t.req.prefilled) for t in self.live]
-        n_log = len(self.log)
         index = len(self.steps)
         gc0, load0 = self.host()
         t0 = self.clock()
@@ -386,8 +416,7 @@ class Loop:
                 if r.prefilled > pre:
                     chunk = (pre, r.prefilled - pre)
             self.live = [t for t in self.live if not t.req.done]
-            eager = [gemm_of(e) for e in self.log[n_log:]]
-            self.steps.append(Step(index, t0, t1, decode_ctx, chunk, eager, gc1 - gc0, load1 - load0))
+            self.steps.append(Step(index, t0, t1, decode_ctx, chunk, gc1 - gc0, load1 - load0))
         return progressed
 
 
@@ -396,6 +425,7 @@ class Window:
     open: float
     close: float
     trace_steps: Tuple[int, int] = (0, 0)  # [first, last) step indices traced
+    engine_metrics: Optional[Tuple[Dict[str, float], Dict[str, float]]] = None  # engine.metrics() at open, at close
 
     @property
     def seconds(self) -> float:
@@ -419,6 +449,7 @@ def fill(loop: Loop, traffic, slots: int, max_steps: int = 2000) -> None:
 
 def run_closed(loop: Loop, traffic, slots: int, seconds: float, tracer=None) -> Window:
     """Keep ``slots`` requests waiting; step for ``seconds``."""
+    at_open = loop.engine.metrics()
     t_open = loop.clock()
     first = len(loop.steps)
     if tracer is not None:
@@ -432,9 +463,10 @@ def run_closed(loop: Loop, traffic, slots: int, seconds: float, tracer=None) -> 
             tracer.maybe_stop(loop, t_open)
         if loop.steps[-1].t1 >= t_open + seconds:
             break
+    at_close = loop.engine.metrics()
     if tracer is not None:
         tracer.stop(loop)
-    w = Window(t_open, loop.steps[-1].t1)
+    w = Window(t_open, loop.steps[-1].t1, engine_metrics=(at_open, at_close))
     w.trace_steps = tracer.steps if tracer is not None else (first, first)
     return w
 
@@ -443,6 +475,7 @@ def run_open(loop: Loop, schedule, seconds: float, drain_s: float, tracer=None) 
     """Offer each request at its due time; step while there is work, wait
     while there is none; then serve every request due in the window to its
     end, for at most ``drain_s`` more."""
+    at_open = loop.engine.metrics()
     t_open = loop.clock()
     end = t_open + seconds
     i = 0
@@ -465,12 +498,13 @@ def run_open(loop: Loop, schedule, seconds: float, drain_s: float, tracer=None) 
         if tracer is not None:
             tracer.maybe_stop(loop, t_open)
     close = loop.clock()
+    at_close = loop.engine.metrics()
     if tracer is not None:
         tracer.stop(loop)
     deadline = close + drain_s
     while loop.clock() < deadline and loop.engine.outstanding():
         loop.step()
-    w = Window(t_open, close)
+    w = Window(t_open, close, engine_metrics=(at_open, at_close))
     w.trace_steps = tracer.steps if tracer is not None else (0, 0)
     return w
 
@@ -493,6 +527,13 @@ class Record:
     peaks: Dict[str, float]
     drain_end: float  # when the loop stopped stepping
     trace: Any = None  # bench.trace.Reduction of the traced steps, or None
+    program: Any = None  # bench.program_spans.Reduction of the traced window, or None
+
+    @property
+    def engine_metrics(self) -> Optional[Tuple[Dict[str, float], Dict[str, float]]]:
+        """The engine's public ``metrics()`` as the window opened and as it
+        closed: its counters, the program's spans' among them."""
+        return self.window.engine_metrics
 
     def window_steps(self) -> List[Step]:
         return [s for s in self.steps if self.window.holds(s.t1)]
@@ -504,11 +545,12 @@ class Record:
         return [(s, self.trace.steps[s.index]) for s in self.steps if s.index in self.trace.steps]
 
     def step_gemms(self, s: Step) -> List[Gemm]:
-        """The Pallas GEMMs one step ran, each once per launch."""
-        out = list(s.eager)
+        """The Pallas GEMMs one step ran, each once per launch: the decode
+        step's, and the chunk step's of its size, a first chunk's too."""
+        out = []
         if s.decode_rows:
             out += self.gemms.get("decode", [])
-        if s.chunk is not None and s.chunk[0] > 0:
+        if s.chunk is not None:
             out += self.gemms.get(f"chunk{s.chunk[1]}", [])
         return out
 

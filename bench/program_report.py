@@ -9,14 +9,15 @@ window.
 ``bench/run.py`` prints its readings and result line unchanged; the lines
 that follow, each starting ``program:``, are this report:
 
-- the per-layer quantities of ``bench/program_spans.py`` (``eager_chunk_ms``,
-  ``eager_idle_share``, ``decode_host_ms``), from the same profile;
+- the quantities of ``bench/program_spans.py`` (``first_chunk_ms``,
+  ``first_chunk_idle_share``, ``decode_host_ms``), from the run's
+  ``Record.program``;
 - the device's idle time inside the benchmark's steps by the innermost
   program span open meanwhile, and the share of it that program spans hold;
 - per span name over the traced steps: count, mean host ms, self ms, idle;
 - Pallas-kernel device ms per decode-only and per chunk step, by GEMM tag;
-- the engine's own counters (``engine.metrics()``) over the window and the
-  drain: each span's count, host seconds and compile-cache loads;
+- the engine's own counters (``Record.engine_metrics``) over the window:
+  each span's count, host seconds and compile-cache loads;
 - the queue split by the engine's ``admit_wall`` stamp: due time to
   admission, the benchmark's own stamp after the admitting step, and
   admission to the first token;
@@ -37,7 +38,7 @@ from pathlib import Path
 CHECKOUT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(CHECKOUT / "src"), str(CHECKOUT)]
 
-from bench import harness, measure, program_spans, run, trace  # noqa: E402
+from bench import harness, measure, program_spans, run  # noqa: E402
 
 _seen = {}
 
@@ -70,45 +71,26 @@ def span_cost(n: int = 20000):
 
 @contextlib.contextmanager
 def _kept():
-    """Keep the engine, its counters around the window, the requests, and
-    the program spans of the profile before ``Tracer.reduce`` removes it."""
-    build, reduce = harness.build, run.Tracer.reduce
-    runs = {name: getattr(harness, name) for name in ("run_open", "run_closed")}
+    """Name the jitted steps' kernels by GEMM tag, and keep the run's record."""
+    build, run_cell = harness.build, run.run_cell
 
     def build_kept(cell, seed):
         built = build(cell, seed)
         # before warm-up traces the jitted steps: their kernels by GEMM tag
         built.engine.tag_kernels = True
-        _seen["engine"] = built.engine
         return built
 
-    def around(inner):
-        def serve(loop, *a, **k):
-            _seen["before"] = dict(_seen["engine"].metrics())
-            window = inner(loop, *a, **k)
-            _seen["after"] = dict(_seen["engine"].metrics())
-            _seen["tracked"] = list(loop.tracked.values())
-            return window
-
-        return serve
-
-    def reduce_kept(self):
-        ops, spans, program = program_spans.events_from_xplane(
-            trace.find_xplane(self.dir), host_stands_in=self.rehearsal
-        )
-        _seen["program"] = program_spans.reduce(ops, spans, program)
-        return reduce(self)
+    def run_kept(*a, **k):
+        out = run_cell(*a, **k)
+        _seen["rec"] = out["rec"]
+        return out
 
     _seen.clear()
-    harness.build, run.Tracer.reduce = build_kept, reduce_kept
-    for name, inner in runs.items():
-        setattr(harness, name, around(inner))
+    harness.build, run.run_cell = build_kept, run_kept
     try:
         yield _seen
     finally:
-        harness.build, run.Tracer.reduce = build, reduce
-        for name, inner in runs.items():
-            setattr(harness, name, inner)
+        harness.build, run.run_cell = build, run_cell
 
 
 def _ms(values, q) -> float:
@@ -117,11 +99,14 @@ def _ms(values, q) -> float:
 
 
 def report() -> None:
-    red = _seen.get("program")
+    rec = _seen.get("rec")
+    if rec is None:
+        return
+    red = rec.program
     if red is not None:
         say(
-            f"eager_chunk_ms {program_spans.eager_chunk_ms(red)}, eager_idle_share "
-            f"{program_spans.eager_idle_share(red)} %, decode_host_ms {program_spans.decode_host_ms(red)}"
+            f"first_chunk_ms {program_spans.first_chunk_ms(red)}, first_chunk_idle_share "
+            f"{program_spans.first_chunk_idle_share(red)} %, decode_host_ms {program_spans.decode_host_ms(red)}"
         )
         idle = program_spans.idle_by_span(red)
         held = sum(v for k, v in idle if k != "engine_step")
@@ -148,8 +133,8 @@ def report() -> None:
         if decode:
             per = [sum(p.end_ns - p.start_ns for p in s.spans if p.name == "engine.step") / 1e6 for s in decode]
             say(f"decode-only steps {len(decode)}, engine.step host ms median {statistics.median(per):.3f}")
-    a, b = _seen.get("before"), _seen.get("after")
-    if a is not None and b is not None:
+    if rec.engine_metrics is not None:
+        a, b = rec.engine_metrics
         parts = []
         for key in sorted(k for k in b if k.endswith(".count")):
             name = key[: -len(".count")]
@@ -161,8 +146,8 @@ def report() -> None:
                 parts.append(f"{name} {n} x {1e3 * s / n:.3f} ms (cache loads {loads}, {1e3 * load_s:.1f} ms)")
         ticks = b["decode_ticks"] - a["decode_ticks"]
         rows = b["decode_rows"] - a["decode_rows"]
-        say("counters over window and drain: " + "; ".join(parts) + f"; decode rows per batch {rows / max(ticks, 1):.3f}")
-    tracked = [t for t in _seen.get("tracked", []) if t.req is not None and t.req.admit_wall]
+        say("counters over the window: " + "; ".join(parts) + f"; decode rows per batch {rows / max(ticks, 1):.3f}")
+    tracked = [t for t in rec.tracked if t.req is not None and t.req.admit_wall]
     if tracked:
         wait = [t.req.admit_wall - t.due for t in tracked]
         loop = [t.admitted - t.due for t in tracked if t.admitted is not None]

@@ -24,7 +24,9 @@ program without them does.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
+import statistics
 from collections import defaultdict
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -32,8 +34,8 @@ from bench import trace
 
 #: prefixes of the program's span names
 PREFIXES = ("engine.", "gemm.")
-#: the span around the eager first chunk of a request
-EAGER = "engine.prefill.first"
+#: the span around a request's first prefill chunk
+FIRST_CHUNK = "engine.prefill.first"
 #: spans that only a step carrying a prefill chunk opens
 PREFILL = ("engine.prefill.first", "engine.prefill.chunk")
 #: the host work of a decode step around its device program
@@ -53,9 +55,10 @@ def events_from_xplane(path: str, host_stands_in: bool = False):
     the first two as ``bench.trace.events_from_xplane`` gives them."""
     from jax.profiler import ProfileData
 
-    ops, spans = trace.events_from_xplane(path, host_stands_in=host_stands_in)
+    data = ProfileData.from_file(path)
+    ops, spans = trace.events(data, path, host_stands_in)
     program: List[ProgramSpan] = []
-    for plane in ProfileData.from_file(path).planes:
+    for plane in data.planes:
         if not plane.name.startswith("/host:CPU"):
             continue
         for line in plane.lines:
@@ -125,6 +128,8 @@ def reduce(ops: Sequence[trace.Op], spans: Sequence[trace.Span], program: Sequen
     def idle(a: float, b: float) -> float:
         return sum((b - a) - trace.covered(m, a, b) for m in merged) / n_dev / 1e9
 
+    launches = sorted((o.start_ns, i) for i, o in enumerate(ops) if o.kernel)
+    starts = [t for t, _ in launches]
     program = sorted(program, key=lambda p: (p.start_ns, -p.end_ns))
     names: Dict[str, NameTotals] = defaultdict(NameTotals)
     per_step: Dict[int, StepProgram] = {}
@@ -152,9 +157,9 @@ def reduce(ops: Sequence[trace.Op], spans: Sequence[trace.Span], program: Sequen
             if name != trace.STEP_SPAN:
                 names[name].idle_s += v
         kernel_s: Dict[str, float] = defaultdict(float)
-        for o in ops:
-            if o.kernel and st.start_ns <= o.start_ns < st.end_ns:
-                kernel_s[kernel_tag(o.name) or "untagged"] += (o.end_ns - o.start_ns) / 1e9
+        for _, i in launches[bisect.bisect_left(starts, st.start_ns) : bisect.bisect_left(starts, st.end_ns)]:
+            o = ops[i]
+            kernel_s[kernel_tag(o.name) or "untagged"] += (o.end_ns - o.start_ns) / 1e9
         per_step[st.step] = StepProgram(inside, dict(self_s), dict(idle_s), dict(kernel_s))
     return Reduction(dict(names), per_step, (hi - lo) / 1e9, step_idle)
 
@@ -166,20 +171,20 @@ def _has_spans(red: Optional[Reduction]) -> bool:
     return red is not None and bool(red.names)
 
 
-def eager_chunk_ms(red: Optional[Reduction]) -> Optional[float]:
-    """Mean host milliseconds of the traced eager first chunks."""
-    if not _has_spans(red) or EAGER not in red.names:
+def first_chunk_ms(red: Optional[Reduction]) -> Optional[float]:
+    """Mean host milliseconds of the traced first prefill chunks."""
+    if not _has_spans(red) or FIRST_CHUNK not in red.names:
         return None
-    t = red.names[EAGER]
+    t = red.names[FIRST_CHUNK]
     return 1e3 * t.host_s / t.count
 
 
-def eager_idle_share(red: Optional[Reduction]) -> Optional[float]:
-    """Device idle inside the eager first chunks, as a share of the traced
+def first_chunk_idle_share(red: Optional[Reduction]) -> Optional[float]:
+    """Device idle inside the first prefill chunks, as a share of the traced
     window (percent)."""
     if not _has_spans(red) or red.window_s <= 0:
         return None
-    t = red.names.get(EAGER)
+    t = red.names.get(FIRST_CHUNK)
     return 100.0 * (t.idle_in_s if t is not None else 0.0) / red.window_s
 
 
@@ -189,7 +194,7 @@ def decode_only(step: StepProgram) -> bool:
 
 
 def decode_host_ms(red: Optional[Reduction]) -> Optional[float]:
-    """Mean host milliseconds per traced decode-only step in the decode
+    """Median host milliseconds per traced decode-only step in the decode
     step's host work: preparing the batch and sampling its tokens."""
     if not _has_spans(red):
         return None
@@ -198,7 +203,7 @@ def decode_host_ms(red: Optional[Reduction]) -> Optional[float]:
         for s in red.steps.values()
         if decode_only(s)
     ]
-    return sum(per) / len(per) if per else None
+    return statistics.median(per) if per else None
 
 
 def idle_by_span(red: Reduction) -> List[Tuple[str, float]]:
@@ -214,7 +219,7 @@ def idle_by_span(red: Reduction) -> List[Tuple[str, float]]:
 def kernel_ms_by_tag(red: Reduction, chunk: bool) -> Dict[str, float]:
     """Mean Pallas-kernel device milliseconds per traced step by GEMM tag:
     over the decode-only steps, or over the steps that carry a prefill
-    chunk (``chunk``): the eager first chunk's kernels are ``untagged``."""
+    chunk (``chunk``); kernels named by their tile alone are ``untagged``."""
     if chunk:
         steps = [s for s in red.steps.values() if {p.name for p in s.spans}.intersection(PREFILL)]
     else:

@@ -12,7 +12,8 @@ the mix for ``--seconds`` by the benchmark's own clock. ``--trace 1``
 profiles the window (at most its first ``trace_s`` seconds, from the mix)
 and reports the per-layer metrics; ``--trace 0`` reports the end-to-end ones.
 Once the window has closed, the served tokens of every finished request
-are checked against the float32 reference (``bench/correct.py``).
+are checked against the float32 reference that the configuration names
+(``bench/correct.py``).
 
 The last line of standard output is one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and with ``--trace 1``
@@ -158,11 +159,15 @@ class Tracer:
         self.steps = (self.first, len(loop.steps))
 
     def reduce(self):
-        from bench import trace
+        """The device's reduction (``bench.trace``) and the program spans'
+        (``bench.program_spans``) of the one trace, which then goes."""
+        from bench import program_spans, trace
 
         try:
-            ops, spans = trace.events_from_xplane(trace.find_xplane(self.dir), host_stands_in=self.rehearsal)
-            return trace.reduce(ops, spans)
+            ops, spans, program = program_spans.events_from_xplane(
+                trace.find_xplane(self.dir), host_stands_in=self.rehearsal
+            )
+            return trace.reduce(ops, spans), program_spans.reduce(ops, spans, program)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -178,13 +183,13 @@ def run_cell(
 
     from repro.core.gemm import gemm_context
 
-    from bench import correct, harness, measure
-    from bench.reference.decoder import RefConfig
+    from bench import correct, harness
 
-    # programs of an earlier run in this process (every eager first chunk
-    # compiles one) are not needed again
+    # programs of an earlier run in this process (limits.py runs many) are
+    # not needed again
     jax.clear_caches()
     gc.collect()
+    reference = cell.reference  # a module that is not there fails here, not after the window
     counter = host_events()
     traffic = cell.traffic(seed)
     built = harness.build(cell, seed)
@@ -194,7 +199,7 @@ def run_cell(
         gemms = harness.warm(built, cell, traffic, ctx.log)
         warm_s = time.perf_counter() - t0
         select_s = built.select_s[0]
-        loop = harness.Loop(built.engine, ctx.log, host=counter.host)
+        loop = harness.Loop(built.engine, host=counter.host)
         tracer = Tracer(loop, float(cell.mix.get("trace_s", TRACE_MAX_S)), rehearsal) if traced else None
         if traffic.closed:
             harness.fill(loop, traffic, slots)
@@ -221,7 +226,7 @@ def run_cell(
         tracked=tracked, gemms=gemms, select_s=select_s, peaks=pk, drain_end=drain_end,
     )
     if tracer is not None:
-        rec.trace = tracer.reduce()
+        rec.trace, rec.program = tracer.reduce()
 
     finished = [
         correct.Served(np.asarray(t.req.prompt), np.asarray(t.req.out_tokens, np.int32), t.offer.max_new)
@@ -233,8 +238,9 @@ def run_cell(
     loop.engine = built.engine = None
     gc.collect()
     t0 = time.perf_counter()
-    ref_cfg = RefConfig.from_config(cell.config)
-    cmp = correct.compare(built.params, ref_cfg, finished, int(cell.engine["max_seq"]), control=control)
+    cmp = correct.compare(
+        reference, built.params, cell.config, finished, int(cell.engine["max_seq"]), control=control
+    )
     cmp["seconds"] = time.perf_counter() - t0
     return dict(
         rec=rec, window=window, cmp=cmp, finished=len(finished), in_window=in_window, warm_s=warm_s,
@@ -323,8 +329,7 @@ def main(argv=None) -> int:
     say(
         "longest steps in the window (ms; garbage collection and compile-cache loads inside): "
         + ", ".join(
-            f"{(s.t1 - s.t0) * 1e3:.1f} (gc {s.gc_s * 1e3:.1f}, loads {s.load_s * 1e3:.1f}"
-            f"{', eager chunk' if s.eager else ''})" for s in longest
+            f"{(s.t1 - s.t0) * 1e3:.1f} (gc {s.gc_s * 1e3:.1f}, loads {s.load_s * 1e3:.1f})" for s in longest
         )
     )
     say(f"garbage collection from the window's open to the drain's end: {out['in_window'][2] * 1e3:.1f} ms")
@@ -336,7 +341,6 @@ def main(argv=None) -> int:
     )
 
     if args.trace:
-        red = rec.trace
         for chunk in (False, True):
             ok, n = measure.matched_steps(rec, chunk)
             pairs = collections.Counter(

@@ -143,7 +143,7 @@ def test_gemms_per_step_come_from_the_selection_log():
         workload=cell.workload, config=cell.config, slots=slots, window=harness.Window(0, 1), steps=[],
         tracked=[], gemms=gemms, select_s=0.0, peaks=peaks.peaks("TPU v5 lite"), drain_end=1.0,
     )
-    step = harness.Step(0, 0.0, 1.0, [20, 30], None, [])
+    step = harness.Step(0, 0.0, 1.0, [20, 30], None)
     runs = rec.launches(rec.step_gemms(step))
     assert runs == 7 * cell.config["num_hidden_layers"] + 1
     # the trace's kernels match when each run has a launch named by a
@@ -156,4 +156,12 @@ def test_gemms_per_step_come_from_the_selection_log():
     assert not rec.kernels_match(step, trace.StepDevice(1.0, 0.5, runs + 1, names[:runs] + ["%other_kernel.3"]))
     chunk = cell.engine["prefill_chunk"]
     assert all(g.m in (chunk, 1) for g in gemms[f"chunk{chunk}"])
+    # a request's first chunk runs the chunk step of its size, as later ones do
+    first, later = harness.Step(1, 0.0, 1.0, [], (0, chunk)), harness.Step(2, 0.0, 1.0, [], (chunk, chunk))
+    assert rec.step_gemms(first) == rec.step_gemms(later) == gemms[f"chunk{chunk}"]
+    widths, shapes = harness.shapes(cell, traffic)
+    for n in traffic.prompt_classes():
+        width = harness.pow2(harness.pages_for(n, cell.engine["page_size"]))
+        assert (min(chunk, n), width) in shapes
+        assert f"chunk{min(chunk, n)}" in gemms
     assert run.TRACE_MAX_S > 0 and np.isfinite(run.TRACE_MAX_S)

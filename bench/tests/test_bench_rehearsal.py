@@ -38,6 +38,7 @@ def test_a_cell_added_from_files_alone(tmp_path):
     only, read from another root. The run is untraced, so of the per-layer
     metrics only those the loop counts have something to read."""
     shutil.copytree(CHECKOUT / "bench" / "configs", tmp_path / "bench" / "configs")
+    shutil.copytree(CHECKOUT / "bench" / "reference", tmp_path / "bench" / "reference")
     shutil.copytree(CHECKOUT / "bench" / "generators", tmp_path / "bench" / "generators")
     shutil.copytree(CHECKOUT / "bench" / "traffic", tmp_path / "bench" / "traffic")
     shutil.copytree(CHECKOUT / "bench" / "metrics", tmp_path / "bench" / "metrics")

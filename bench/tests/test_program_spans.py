@@ -9,7 +9,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import harness, measure, program_spans, run, trace
+from bench import harness, measure, peaks, program_spans, run, trace
 
 FIXTURES = Path(__file__).parent / "fixtures"
 ENGINE_SPANS = {
@@ -27,7 +27,7 @@ def _ps(name, start, end, **args):
 
 
 def _hand_made():
-    # step 0 (0-100): an eager first chunk 10-60 holding a selection 20-30,
+    # step 0 (0-100): a first chunk 10-60 holding a selection 20-30,
     # the device busy 50-70; step 1 (100-200): a decode step, prepare
     # 105-115, dispatch 115-120, wait 120-180 while the device runs 118-175,
     # sample 180-190
@@ -60,8 +60,8 @@ def test_idle_goes_to_the_innermost_program_span():
     assert s1["engine.decode.prepare"] == pytest.approx(10e-9)
     assert s1["engine.decode.dispatch"] == pytest.approx(3e-9)
     assert s1["engine.decode.wait"] == pytest.approx(5e-9)
-    eager = red.names["engine.prefill.first"]
-    assert eager.idle_s == pytest.approx(30e-9) and eager.idle_in_s == pytest.approx(40e-9)
+    first = red.names["engine.prefill.first"]
+    assert first.idle_s == pytest.approx(30e-9) and first.idle_in_s == pytest.approx(40e-9)
     assert red.step_idle_s == pytest.approx(sum(s0.values()) + sum(s1.values()))
     assert "engine.admit" not in red.names
 
@@ -87,8 +87,8 @@ def test_kernel_time_goes_to_the_tag_in_the_kernels_name():
 
 def test_the_per_layer_quantities():
     red = program_spans.reduce(*_hand_made())
-    assert program_spans.eager_chunk_ms(red) == pytest.approx(50e-6)
-    assert program_spans.eager_idle_share(red) == pytest.approx(100 * 40 / 200)
+    assert program_spans.first_chunk_ms(red) == pytest.approx(50e-6)
+    assert program_spans.first_chunk_idle_share(red) == pytest.approx(100 * 40 / 200)
     assert program_spans.decode_host_ms(red) == pytest.approx(20e-6)  # prepare + sample of step 1
     idle = dict(program_spans.idle_by_span(red))
     assert sum(idle.values()) == pytest.approx(red.step_idle_s)
@@ -120,22 +120,23 @@ def test_a_trace_without_program_spans_reads_nothing():
     ops, spans = _fixture()
     red = program_spans.reduce(ops, spans, [])
     assert red.names == {} and red.step_idle_s == pytest.approx(0.009978407)
-    for read in (program_spans.eager_chunk_ms, program_spans.eager_idle_share, program_spans.decode_host_ms):
+    for read in (program_spans.first_chunk_ms, program_spans.first_chunk_idle_share, program_spans.decode_host_ms):
         assert read(red) is None and read(None) is None
 
 
 def test_a_traced_rehearsal_holds_every_engine_span(monkeypatch, capsys):
     """``--trace 1 --cpu-rehearsal``: the profile holds every span the
-    engine opens, each inside a benchmark step, and the eager first chunk
-    holds the selections its trace makes."""
+    engine opens, each inside a benchmark step, and no selection: warm-up
+    has traced every program the window runs, a request's first chunk
+    among them."""
     got = {}
-    reduce = run.Tracer.reduce
+    events = program_spans.events_from_xplane
 
-    def keep(self):
-        got["events"] = program_spans.events_from_xplane(trace.find_xplane(self.dir), host_stands_in=True)
-        return reduce(self)
+    def keep(path, host_stands_in=False):
+        got["events"] = events(path, host_stands_in)
+        return got["events"]
 
-    monkeypatch.setattr(run.Tracer, "reduce", keep)
+    monkeypatch.setattr(program_spans, "events_from_xplane", keep)
     code = run.main(
         ["--workload", "granite-8b.prefill-poisson", "--seed", str(2**31 + 11), "--seconds", "3",
          "--trace", "1", "--cpu-rehearsal", "--backend", "xla"]
@@ -147,12 +148,11 @@ def test_a_traced_rehearsal_holds_every_engine_span(monkeypatch, capsys):
     assert ENGINE_SPANS <= {p.name for p in program}
     for p in program:
         assert any(s.start_ns <= p.start_ns and p.end_ns <= s.end_ns for s in steps), p
-    eager = [p for p in program if p.name == "engine.prefill.first"]
-    selects = [p for p in program if p.name == "gemm.select"]
-    assert selects and all(any(e.start_ns <= s.start_ns and s.end_ns <= e.end_ns for e in eager) for s in selects)
-    assert {"tag", "source"} <= set(selects[0].args) and {"uid", "tokens"} <= set(eager[0].args)
+    first = [p for p in program if p.name == "engine.prefill.first"]
+    assert not [p for p in program if p.name == "gemm.select"]
+    assert all({"uid", "tokens"} <= set(f.args) for f in first)
     red = program_spans.reduce(ops, spans, program)
-    assert program_spans.eager_chunk_ms(red) > 0 and program_spans.eager_idle_share(red) > 0
+    assert program_spans.first_chunk_ms(red) > 0 and program_spans.first_chunk_idle_share(red) > 0
     # a loaded host may carry a prefill chunk in every step of so short a window
     if any(program_spans.decode_only(s) for s in red.steps.values()):
         assert program_spans.decode_host_ms(red) > 0
@@ -171,7 +171,7 @@ def test_decode_counters_match_the_loops_batch_count():
     slots = cell.engine["slots"]
     with gemm_context(selector=built.selector, backend="xla") as ctx:
         harness.warm(built, cell, traffic, ctx.log)
-        loop = harness.Loop(built.engine, ctx.log)
+        loop = harness.Loop(built.engine)
         harness.fill(loop, traffic, slots)
         eng = built.engine
         rows0, ticks0, first = eng.decode_rows, eng.decode_ticks, len(loop.steps)
@@ -196,18 +196,42 @@ def test_the_program_report_reads_a_traced_rehearsal(capsys):
     and leaves the benchmark's functions as it found them."""
     from bench import program_report
 
-    before = (harness.build, harness.run_open, harness.run_closed, run.Tracer.reduce)
+    before = (harness.build, run.run_cell)
     code = program_report.main(
         ["--workload", "granite-8b.prefill-poisson", "--seed", str(2**31 + 5), "--seconds", "3",
          "--cpu-rehearsal", "--backend", "xla"]
     )
     out = capsys.readouterr().out
     assert code == 0
-    assert (harness.build, harness.run_open, harness.run_closed, run.Tracer.reduce) == before
+    assert (harness.build, run.run_cell) == before
     lines = [x for x in out.splitlines() if x.startswith("program: ")]
     text = "\n".join(lines)
-    for part in ("eager_chunk_ms ", "program spans hold", "idle by innermost span", "span engine.prefill.first:",
+    for part in ("first_chunk_ms ", "program spans hold", "idle by innermost span", "span engine.prefill.first:",
                  "Pallas kernel ms per decode-only step by tag",
-                 "counters over window and drain:", "due to admit_wall", "span cost:"):
+                 "counters over the window:", "due to admit_wall", "span cost:"):
         assert part in text, part
-    assert program_spans.eager_chunk_ms(program_report._seen["program"]) > 0
+    assert program_spans.first_chunk_ms(program_report._seen["rec"].program) > 0
+
+
+def test_a_traced_rehearsal_fills_the_record_for_its_readers():
+    """A traced run's record holds the program spans' reduction and the
+    engine's counters as the window opened and closed, and the reader of
+    ``decode_host_ms.decode`` finds the decode steps' host work there; an
+    untraced run's record holds no reduction."""
+    cell = harness.Cell.load(harness.CHECKOUT, "granite-8b.decode-batch", rehearsal=True)
+    # long outputs, so that the window holds decode-only steps
+    cell.mix = {**cell.mix, "output": {"dist": "fixed", "value": 16}}
+    pk = peaks.peaks("TPU v5 lite")
+    rec = run.run_cell(cell, 2**31 + 3, 3.0, traced=True, backend="xla", pk=pk, rehearsal=True)["rec"]
+    assert isinstance(rec.program, program_spans.Reduction) and rec.program.names
+    at_open, at_close = rec.engine_metrics
+    window_ticks = sum(1 for s in rec.window_steps() if s.decode_rows)
+    assert at_close["decode_ticks"] - at_open["decode_ticks"] == window_ticks > 0
+    assert at_close["engine.step.count"] - at_open["engine.step.count"] == len(rec.window_steps())
+    metric = next(m for m in cell.per_layer() if m["name"] == "decode_host_ms.decode")
+    assert metric["source"] == "program_span" and metric["moves"] == "output_tok_per_s"
+    reader = harness.load_module(harness.CHECKOUT / "bench" / "metrics" / "decode_host_ms.decode.py", "reader")
+    assert reader.read(rec) > 0
+    assert run.per_layer(cell, harness.CHECKOUT, rec)["decode_host_ms.decode"]["value"] == reader.read(rec)
+    untraced = run.run_cell(cell, 2**31 + 3, 1.0, traced=False, backend="xla", pk=pk, rehearsal=True)["rec"]
+    assert untraced.program is None and reader.read(untraced) is None

@@ -58,7 +58,11 @@ def events_from_xplane(path: str, host_stands_in: bool = False) -> Tuple[List[Op
     rehearsal): then the host's XLA operations stand in for the device's."""
     from jax.profiler import ProfileData
 
-    data = ProfileData.from_file(path)
+    return events(ProfileData.from_file(path), path, host_stands_in)
+
+
+def events(data, path: str, host_stands_in: bool = False) -> Tuple[List[Op], List[Span]]:
+    """:func:`events_from_xplane` of a trace already read (``ProfileData``)."""
     ops: List[Op] = []
     host_ops: List[Op] = []
     spans: List[Span] = []
